@@ -105,6 +105,23 @@ class CollectiveStats:
         return " ".join(parts) if parts else "none"
 
 
+def collective_ops(hlo_text: str) -> List[Tuple[str, str]]:
+    """``(collective, result shape)`` per collective instruction, with
+    async ``-start`` forms folded into their base op — e.g.
+    ``("collective-permute", "u8[1,4096]{1,0}")``.  What a check reads to
+    see which dtype crosses devices."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m or m.group(2).endswith("-done"):
+            continue
+        for c in _COLLECTIVES:
+            if m.group(2) == c or m.group(2).startswith(c + "-"):
+                out.append((c, m.group(1)))
+                break
+    return out
+
+
 def parse_collectives(hlo_text: str) -> CollectiveStats:
     counts: Dict[str, int] = {}
     by: Dict[str, int] = {}
@@ -176,21 +193,9 @@ class Roofline:
         return self.model_flops / denom if denom else 0.0
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    jax 0.4.x returns a list with one dict per computation; newer jax
-    returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def roofline_from_compiled(compiled, model_flops: float, chips: int,
                            hw: Dict[str, float] = HW) -> Roofline:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     nbytes = float(ca.get("bytes accessed", 0.0))
     stats = parse_collectives(compiled.as_text())

@@ -76,8 +76,8 @@ path-independent (the vpb row alignment makes the bucketed payload equal
 the per-leaf sum exactly), so ``path="auto"`` never changes the ledger.
 
 Sharded meshes: the Moniqua backends tile each worker's slice separately
-(``kernels/ops.py`` stacked wrappers vmap the tile layout over the worker
-axis), so the only cross-worker traffic in a round is the packed
+(``kernels/ops.py`` stacked wrappers launch the kernels once per worker),
+so the only cross-worker traffic in a round is the packed
 collective-permute of the payload, and — because every worker hashes the
 same (seed, element) pairs — stochastic rounding uses Supp.-C shared
 randomness exactly: identical models encode to identical payloads on
@@ -128,7 +128,6 @@ from repro.core.quantizers import (QuantSpec, ef_qsgd_encode_segmented,
 from repro.core.topology import (HierarchicalTopology, Topology,
                                  normalize_mask)
 from repro.kernels import ops as kops
-from repro.kernels import ref as kref
 from repro.kernels.moniqua_encode import (DEFAULT_BLOCK_COLS,
                                           DEFAULT_BLOCK_ROWS)
 from repro.obs import metrics as obs_metrics
@@ -507,7 +506,7 @@ class RoundPlan:
                 return (kops.moniqua_encode_chunk(
                     self.flat, c.offset - self.base, c.size, self.B,
                     eng.codec.spec, self.seed, backend=self.backend,
-                    idx_base=c.offset),)
+                    idx_base=c.offset, worker_axes=eng.worker_axes),)
             if name == "qsgd":
                 packed, scales = qsgd_encode_segmented(
                     self._win(self.flat, c), eng.codec.spec, self.seed,
@@ -593,7 +592,7 @@ class RoundPlan:
                     return kops.moniqua_decode_reduce_chunk(
                         enc[0], nbrs, self.flat, c.offset - self.base,
                         c.size, self.B, weights, spec,
-                        backend=self.backend)
+                        backend=self.backend, worker_axes=eng.worker_axes)
                 # masked: one fused decode-reduce per surviving offset
                 # (single weight), recombined as win + sum of gated diffs
                 win = self._win(self.flat, c).astype(jnp.float32)
@@ -602,7 +601,7 @@ class RoundPlan:
                     mixed_o = kops.moniqua_decode_reduce_chunk(
                         enc[0], nbrs[k:k + 1], self.flat,
                         c.offset - self.base, c.size, self.B, (w,), spec,
-                        backend=self.backend)
+                        backend=self.backend, worker_axes=eng.worker_axes)
                     out = out + gate(o, mixed_o.astype(jnp.float32) - win)
                 return out.astype(self._win(self.flat, c).dtype)
             if name == "qsgd":
@@ -874,6 +873,12 @@ class CommEngine:
     Pallas and per-leaf payloads by the parity contracts).  When off, the
     flag is a Python-level branch: the telemetry graph is never traced,
     hence dead-code-free under jit.
+
+    ``worker_axes`` names the mesh axes the stacked worker dim is sharded
+    over (``()`` = not sharded).  With it the Moniqua codec kernels run
+    under ``shard_map`` on those axes (``kernels/ops.py``), so each device
+    encodes and decode-reduces its own workers and only the packed payload
+    crosses devices; the caller enters the mesh with ``jax.set_mesh``.
     """
     topo: Any                     # Topology | HierarchicalTopology
     codec: Any = dataclasses.field(default_factory=MoniquaWire)
@@ -881,12 +886,16 @@ class CommEngine:
     path: str = "auto"
     chunks: int = 1
     telemetry: bool = False
+    worker_axes: Tuple[str, ...] = ()
 
     def __post_init__(self) -> None:
         if self.path not in PATHS:
             raise ValueError(f"unknown path {self.path!r}; one of {PATHS}")
         if int(self.chunks) < 1:
             raise ValueError(f"chunks must be >= 1, got {self.chunks}")
+        if self.worker_axes and self.tiered:
+            raise ValueError("sharded codec kernels (worker_axes) are "
+                             "single-tier only")
 
     # -- hierarchy plumbing ------------------------------------------------
     @property
@@ -1278,7 +1287,8 @@ class CommEngine:
             if presence is None:
                 mixed_ref = kops.moniqua_decode_reduce_stacked(
                     carry["packed"], p_nbrs, carry["ref"], carry["B"],
-                    weights, spec, backend=backend)
+                    weights, spec, backend=backend,
+                    worker_axes=self.worker_axes)
                 delta = mixed_ref - carry["ref"]
             else:
                 # elastic: gate each offset's decoded diff by the edge's
@@ -1287,7 +1297,8 @@ class CommEngine:
                 for k, (o, w) in enumerate(zip(offsets, weights)):
                     mixed_o = kops.moniqua_decode_reduce_stacked(
                         carry["packed"], p_nbrs[k:k + 1], carry["ref"],
-                        carry["B"], (w,), spec, backend=backend)
+                        carry["B"], (w,), spec, backend=backend,
+                        worker_axes=self.worker_axes)
                     delta = delta + jnp.where(
                         _alive_cols(presence, o),
                         mixed_o - carry["ref"], 0.0)
@@ -1296,8 +1307,9 @@ class CommEngine:
         # encode round k from the post-mix model, for consumption at k+1
         B = modulo.b_theta(theta, spec.delta)
         with obs_trace.named_phase("comm.encode"):
-            packed = kops.moniqua_encode_stacked(out, B, spec, seed,
-                                                 backend=backend)
+            packed = kops.moniqua_encode_stacked(
+                out, B, spec, seed, backend=backend,
+                worker_axes=self.worker_axes)
         new_carry = {"packed": packed, "ref": out,
                      "B": jnp.asarray(B, jnp.float32),
                      "valid": jnp.ones((), jnp.bool_)}
@@ -1497,18 +1509,20 @@ class CommEngine:
             # one rounding-uniform stream per element (Supp. C)
             packed = kops.moniqua_encode_stacked(x, B, spec, seed,
                                                  backend=backend,
-                                                 idx_base=idx_base)
+                                                 idx_base=idx_base,
+                                                 worker_axes=self.worker_axes)
             p_nbrs = jnp.stack([gossip._roll(packed, o) for o in offsets])
             if presence is None:
                 return kops.moniqua_decode_reduce_stacked(
-                    packed, p_nbrs, x, B, weights, spec, backend=backend)
+                    packed, p_nbrs, x, B, weights, spec, backend=backend,
+                    worker_axes=self.worker_axes)
             # elastic: fused decode-reduce per surviving offset, gated
             f = x.astype(jnp.float32)
             out = f
             for k, (o, w) in enumerate(zip(offsets, weights)):
                 mixed_o = kops.moniqua_decode_reduce_stacked(
                     packed, p_nbrs[k:k + 1], x, B, (w,), spec,
-                    backend=backend)
+                    backend=backend, worker_axes=self.worker_axes)
                 out = out + jnp.where(_alive_cols(presence, o, x.ndim),
                                       mixed_o.astype(jnp.float32) - f, 0.0)
             return out.astype(x.dtype)
@@ -1616,7 +1630,7 @@ class CommEngine:
             n_last = xi.shape[-1]
 
             def val(p):
-                return kref.value_ref(p, B, spec.bits)[..., :n_last]
+                return kops.moniqua_unpack_value(p, B, spec, n_last)
 
             xj_at_i = modulo.recover(val(pj), xi, B)
             xi_at_j = modulo.recover(val(pi), xj, B)

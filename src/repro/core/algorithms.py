@@ -88,6 +88,7 @@ class AlgoHyper:
     tiers: int = 1                # 1 = flat gossip; k>1 = two-tier, nodes of k
     presence: Optional[Tuple[int, ...]] = None   # elastic 0/1 worker mask
     deadline: Optional[float] = None             # sim round deadline (s)
+    worker_axes: Tuple[str, ...] = ()  # mesh axes of the worker dim (engine)
 
     def comm_topo(self):
         """The topology the engines gossip on: ``topo`` itself for flat
@@ -115,7 +116,8 @@ class AlgoHyper:
                           make_wire(self.wire, self.codec.spec,
                                     warmup=self.warmup),
                           self.backend, path=self.path, chunks=self.chunks,
-                          telemetry=self.telemetry)
+                          telemetry=self.telemetry,
+                          worker_axes=self.worker_axes)
 
     def exact_engine(self, telemetry: bool = False) -> CommEngine:
         """Full-precision engine.  ``telemetry`` is opt-in per call site:
@@ -123,7 +125,7 @@ class AlgoHyper:
         internal replica/estimator mixing (Choco, DCD, ...) leaves it off."""
         return CommEngine(self.comm_topo(), FullPrecisionWire(),
                           self.backend, path=self.path, chunks=self.chunks,
-                          telemetry=telemetry)
+                          telemetry=telemetry, worker_axes=self.worker_axes)
 
 
 # ---------------------------------------------------------------------------

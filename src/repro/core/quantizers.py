@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import packing
+
 
 def delta_for_bits(bits: int, stochastic: bool = True) -> float:
     """Worst-case error of a ``bits``-wide linear quantizer on [-1/2, 1/2].
@@ -138,33 +140,18 @@ def packed_last_dim(n: int, bits: int) -> int:
 def pack_codes(codes: jax.Array, bits: int) -> jax.Array:
     """Pack integer codes (< 2**bits) into uint8 along the last axis.
 
-    Pads the last axis with zeros up to a multiple of ``values_per_byte``.
+    Pads the last axis with zeros up to a multiple of ``values_per_byte``;
+    code ``b*vpb + j`` lands in byte ``b``, bit slot ``j`` (layout and its
+    TPU-friendly computation: ``core/packing.py``).
     """
-    if bits == 8:
-        return codes.astype(jnp.uint8)
-    vpb = 8 // bits
-    n = codes.shape[-1]
-    pad = (-n) % vpb
-    if pad:
-        pad_width = [(0, 0)] * (codes.ndim - 1) + [(0, pad)]
-        codes = jnp.pad(codes, pad_width)
-    grouped = codes.reshape(*codes.shape[:-1], -1, vpb).astype(jnp.uint8)
-    shifts = (jnp.arange(vpb, dtype=jnp.uint8) * bits).astype(jnp.uint8)
-    packed = jnp.zeros(grouped.shape[:-1], dtype=jnp.uint8)
-    for j in range(vpb):
-        packed = packed | (grouped[..., j] << shifts[j])
-    return packed
+    return packing.pack(codes, bits)
 
 
 def unpack_codes(packed: jax.Array, bits: int, n: int) -> jax.Array:
     """Inverse of :func:`pack_codes`; ``n`` is the original last-axis length."""
     if bits == 8:
-        return packed
-    vpb = 8 // bits
-    mask = jnp.uint8(2 ** bits - 1)
-    parts = [((packed >> jnp.uint8(j * bits)) & mask) for j in range(vpb)]
-    codes = jnp.stack(parts, axis=-1).reshape(*packed.shape[:-1], -1)
-    return codes[..., :n]
+        return packed[..., :n]
+    return packing.unpack(packed, bits, n).astype(jnp.uint8)
 
 
 # ---------------------------------------------------------------------------
@@ -184,6 +171,9 @@ def _counter_uniform(seed: jax.Array, idx: jax.Array) -> jax.Array:
     Counter-based so that encode needs no PRNG-state threading and so the
     same (seed, element) pair draws the same uniform on every worker — the
     shared-randomness convention the Pallas encode kernel also uses.
+
+    The final cast goes through int32: Mosaic has no ``uint32 -> float32``
+    conversion, and ``h >> 8`` fits in 24 bits, so the detour is exact.
     """
     h = (idx.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)) ^ seed.astype(jnp.uint32)
     h = h ^ (h >> 16)
@@ -191,7 +181,8 @@ def _counter_uniform(seed: jax.Array, idx: jax.Array) -> jax.Array:
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def qsgd_encode(x: jax.Array, spec: QuantSpec,

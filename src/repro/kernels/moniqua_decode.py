@@ -7,7 +7,8 @@ receiver's own model tile ``y`` (the Lemma 1 reference), produce
     x_hat = (q * B) - (y mod B) + y           (mode="self",   line 4)
 
 in a single VMEM pass: one packed read (bits/8 bytes/elem) + one y read +
-one f32/bf16 write.  The two modes share the unpack/dequant prologue.
+one f32/bf16 write.  The two modes share the decode-reduce kernel's
+chunked MXU unpack (``moniqua_decode_reduce.tile_values``).
 """
 from __future__ import annotations
 
@@ -17,37 +18,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import packing
+from repro.kernels.moniqua_decode_reduce import chunk_loop, tile_values
+
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_COLS = 1024
 
 
-def _decode_kernel(p_ref, y_ref, b_ref, o_ref, *, bits: int, mode: str):
-    levels = 2 ** bits
-    vpb = 8 // bits
-    rows, pcols = p_ref.shape
+def _decode_kernel(p_ref, y_ref, b_ref, *refs, bits: int, mode: str):
+    o_ref = refs[-1]
+    umat = refs[0][...] if len(refs) == 2 else None
     B = b_ref[0]
-    p = p_ref[...].astype(jnp.uint32)
-
-    if vpb == 1:
-        codes = p.astype(jnp.float32)
-    else:
-        mask = jnp.uint32(2 ** bits - 1)
-        subs = [((p >> jnp.uint32(s * bits)) & mask) for s in range(vpb)]
-        # value at column (b*vpb + s) comes from byte b, slot s
-        codes = jnp.stack(subs, axis=-1).reshape(rows, pcols * vpb)
-        codes = codes.astype(jnp.float32)
-
-    qb = ((codes + 0.5) / levels - 0.5) * B
-    y = y_ref[...].astype(jnp.float32)
-    if mode == "remote":
-        d = qb - y
-        out = (d - B * jnp.floor(d / B + 0.5)) + y      # cmod(q*B - y, B) + y
-    elif mode == "self":
-        ymod = y - B * jnp.floor(y / B + 0.5)           # cmod(y, B)
-        out = qb - ymod + y
-    else:
-        raise ValueError(mode)
-    o_ref[...] = out.astype(o_ref.dtype)
+    for cs, ps in chunk_loop(bits, y_ref.shape[1]):
+        qb = tile_values(p_ref[:, ps], bits, B, umat)
+        y = y_ref[:, cs].astype(jnp.float32)
+        if mode == "remote":
+            d = qb - y
+            out = (d - B * jnp.floor(d / B + 0.5)) + y   # cmod(q*B - y, B) + y
+        elif mode == "self":
+            ymod = y - B * jnp.floor(y / B + 0.5)        # cmod(y, B)
+            out = qb - ymod + y
+        else:
+            raise ValueError(mode)
+        o_ref[:, cs] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "mode", "block_rows",
@@ -65,15 +58,21 @@ def decode(packed: jax.Array, y2d: jax.Array, B: jax.Array, *, bits: int,
                          f"({block_rows},{block_cols}); pad in ops.py")
     grid = (rows // block_rows, cols // block_cols)
     kernel = functools.partial(_decode_kernel, bits=bits, mode=mode)
+    in_specs = [
+        pl.BlockSpec((block_rows, block_cols // vpb), lambda i, j: (i, j)),
+        pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
+        pl.BlockSpec((1,), lambda i, j: (0,)),
+    ]
+    args = [packed, y2d, jnp.asarray(B, jnp.float32).reshape(1)]
+    if vpb > 1:
+        umat = packing.unpack_matrix(bits)
+        in_specs.append(pl.BlockSpec(umat.shape, lambda i, j: (0, 0)))
+        args.append(umat)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_cols // vpb), lambda i, j: (i, j)),
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), y2d.dtype),
         interpret=interpret,
-    )(packed, y2d, jnp.asarray(B, jnp.float32).reshape(1))
+    )(*args)

@@ -24,9 +24,10 @@ The neighbor weights are *compile-time constants* (they come from the static
 ``Topology``), so the reduction fully unrolls with no weight operand; only
 ``B`` (a function of the traced theta schedule) is a runtime scalar.
 
-Bit-exactness contract: ``decode_reduce_values`` is the single source of the
-per-element math for BOTH the kernel body and the pure-jnp backend
-(``ops.moniqua_decode_reduce_jnp``).  Every *inexact* multiply is routed
+Bit-exactness contract: ``decode_reduce_values`` (after :func:`dequant`) is
+the single source of the per-element math for BOTH the kernel body and the
+pure-jnp backend (``ops.moniqua_decode_reduce_jnp``), and both unpack with
+the same ``core/packing.py`` chunk product.  Every *inexact* multiply is routed
 through ``_shield`` — ``where(v == v, v, 0)``, a per-element NaN check no
 optimizer can fold — because LLVM's FMA contraction otherwise fuses the
 multiply with a downstream add/sub *through* HLO ``optimization_barrier``s
@@ -45,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import packing
+
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_COLS = 1024
 
@@ -54,21 +57,35 @@ def _shield(v: jax.Array) -> jax.Array:
     return jnp.where(v == v, v, jnp.zeros_like(v))
 
 
+def dequant(codes: jax.Array, bits: int, B) -> jax.Array:
+    """Integral f32 codes -> transmitted values ``q * B``."""
+    # /levels is a power of two (exact); the *B product is not — shield it
+    return _shield(((codes + 0.5) / 2 ** bits - 0.5) * B)
+
+
 def unpack_values(p: jax.Array, bits: int, B) -> jax.Array:
     """packed uint8 array -> dequantized f32 values scaled by B (q * B)."""
-    levels = 2 ** bits
-    vpb = 8 // bits
-    p = p.astype(jnp.uint32)
-    if vpb == 1:
-        codes = p.astype(jnp.float32)
+    return dequant(packing.unpack(p, bits, p.shape[-1] * (8 // bits)),
+                   bits, B)
+
+
+def tile_values(p: jax.Array, bits: int, B, umat=None) -> jax.Array:
+    """In-kernel :func:`unpack_values` of one chunk of ``packing.LANES``
+    packed bytes (``umat`` = ``packing.unpack_matrix``, unused at 8 bits)."""
+    if bits == 8:
+        codes = p.astype(jnp.int32).astype(jnp.float32)
     else:
-        mask = jnp.uint32(2 ** bits - 1)
-        subs = [((p >> jnp.uint32(s * bits)) & mask) for s in range(vpb)]
-        codes = jnp.stack(subs, axis=-1).reshape(*p.shape[:-1],
-                                                 p.shape[-1] * vpb)
-        codes = codes.astype(jnp.float32)
-    # /levels is a power of two (exact); the *B product is not — shield it
-    return _shield(((codes + 0.5) / levels - 0.5) * B)
+        codes = packing.unpack_chunk(p, umat, bits)
+    return dequant(codes, bits, B)
+
+
+def chunk_loop(bits: int, cols: int):
+    """Static ``(code columns, byte columns)`` slices of one tile row: one
+    chunk of ``packing.LANES`` packed bytes each."""
+    w = packing.chunk_elems(bits)
+    return [(slice(k * w, (k + 1) * w),
+             slice(k * packing.LANES, (k + 1) * packing.LANES))
+            for k in range(cols // w)]
 
 
 def decode_reduce_values(qb_self: jax.Array, qb_nbrs, y: jax.Array, B,
@@ -119,13 +136,20 @@ def alias_band_mask(qb: jax.Array, y: jax.Array, B, theta) -> jax.Array:
     return jnp.abs(dhat) >= jnp.asarray(theta, jnp.float32)
 
 
-def _decode_reduce_kernel(ps_ref, pn_ref, y_ref, b_ref, o_ref, *,
+def _decode_reduce_kernel(ps_ref, pn_ref, y_ref, b_ref, *refs,
                           bits: int, weights: tuple):
+    """``refs`` is ``(unpack_matrix_ref, o_ref)`` below 8 bits, else
+    ``(o_ref,)``.  Works one 128-byte chunk of the payloads at a time, which
+    also bounds the f32 temporaries to one chunk per neighbor."""
+    o_ref = refs[-1]
+    umat = refs[0][...] if len(refs) == 2 else None
     B = b_ref[0]
-    qb_self = unpack_values(ps_ref[...], bits, B)
-    qb_nbrs = [unpack_values(pn_ref[s], bits, B) for s in range(len(weights))]
-    out = decode_reduce_values(qb_self, qb_nbrs, y_ref[...], B, weights)
-    o_ref[...] = out.astype(o_ref.dtype)
+    for cs, ps in chunk_loop(bits, y_ref.shape[1]):
+        qb_self = tile_values(ps_ref[:, ps], bits, B, umat)
+        qb_nbrs = [tile_values(pn_ref[s, :, ps], bits, B, umat)
+                   for s in range(len(weights))]
+        out = decode_reduce_values(qb_self, qb_nbrs, y_ref[:, cs], B, weights)
+        o_ref[:, cs] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "weights", "block_rows",
@@ -151,17 +175,23 @@ def decode_reduce(p_self: jax.Array, p_nbrs: jax.Array, y2d: jax.Array,
     grid = (rows // block_rows, cols // block_cols)
     kernel = functools.partial(_decode_reduce_kernel, bits=bits,
                                weights=tuple(weights))
+    in_specs = [
+        pl.BlockSpec((block_rows, block_cols // vpb), lambda i, j: (i, j)),
+        pl.BlockSpec((m, block_rows, block_cols // vpb),
+                     lambda i, j: (0, i, j)),
+        pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
+        pl.BlockSpec((1,), lambda i, j: (0,)),
+    ]
+    args = [p_self, p_nbrs, y2d, jnp.asarray(B, jnp.float32).reshape(1)]
+    if vpb > 1:
+        umat = packing.unpack_matrix(bits)
+        in_specs.append(pl.BlockSpec(umat.shape, lambda i, j: (0, 0)))
+        args.append(umat)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_cols // vpb), lambda i, j: (i, j)),
-            pl.BlockSpec((m, block_rows, block_cols // vpb),
-                         lambda i, j: (0, i, j)),
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), y2d.dtype),
         interpret=interpret,
-    )(p_self, p_nbrs, y2d, jnp.asarray(B, jnp.float32).reshape(1))
+    )(*args)

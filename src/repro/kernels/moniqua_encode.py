@@ -11,8 +11,11 @@ of the cost of a f32 copy.
 TPU adaptation notes (vs a CUDA bit-twiddling port):
   * tiles are (block_rows × block_cols) with block_cols a multiple of
     128·values_per_byte so the *packed* output tile keeps the 128-lane layout;
-  * the pack is expressed as ``vpb`` strided sub-tiles OR-ed with shifts —
-    a reshape-free formulation that maps onto VREG shuffles, not scatter;
+  * the interleaving pack runs on the MXU, one 128-lane chunk of packed
+    bytes at a time (``core/packing.py``): Mosaic has no lane shuffle
+    for it;
+  * every float <-> integer cast goes through int32 (Mosaic has no
+    float <-> uint32 conversion);
   * stochastic rounding uses a counter-based murmur3 hash of the global
     element index (shared randomness across workers, Supp. C) instead of a
     stateful PRNG, so grid blocks are independent and replayable.
@@ -25,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import packing
 # the shared counter-based hash: kernel and every jnp path must draw the
 # same uniform per (seed, element) or bit-exactness breaks
 from repro.core.quantizers import _counter_uniform as _hash_uniform
@@ -33,9 +37,11 @@ DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_COLS = 1024  # multiple of 128 * max vpb (8)
 
 
-def _encode_kernel(x_ref, seed_ref, b_ref, o_ref, *, bits: int,
+def _encode_kernel(x_ref, seed_ref, b_ref, *refs, bits: int,
                    stochastic: bool, ncols: int):
     """One (rows, cols) tile -> (rows, cols/vpb) packed tile.
+
+    ``refs`` is ``(pack_matrix_ref, o_ref)`` below 8 bits, else ``(o_ref,)``.
 
     ``seed_ref`` carries two replicated uint32 scalars: the hash seed and
     ``idx_base``, the flat-index offset of this array inside a larger
@@ -59,26 +65,27 @@ def _encode_kernel(x_ref, seed_ref, b_ref, o_ref, *, bits: int,
 
     if stochastic:
         # global flat element index (row-major over the full padded array)
-        row_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
-        col_ids = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
-        g_rows = row_ids + jnp.uint32(i * rows)
-        g_cols = col_ids + jnp.uint32(j * cols)
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        col_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        g_rows = (row_ids + i * rows).astype(jnp.uint32)
+        g_cols = (col_ids + j * cols).astype(jnp.uint32)
         idx = seed_ref[1] + g_rows * jnp.uint32(ncols) + g_cols
         u = _hash_uniform(seed_ref[0], idx)
         c = jnp.floor(lat + u)
     else:
         c = jnp.floor(lat + 0.5)
-    c = jnp.clip(c, 0, levels - 1).astype(jnp.uint32)
+    c = jnp.clip(c, 0, levels - 1)                 # integral f32 codes
 
     if vpb == 1:
-        o_ref[...] = c.astype(jnp.uint8)
+        refs[-1][...] = c.astype(jnp.int32).astype(jnp.uint8)
         return
-    # pack: value v at column (b*vpb + j) lands in byte b, bit-slot j.
-    c3 = c.reshape(rows, cols // vpb, vpb)
-    packed = c3[:, :, 0]
-    for s in range(1, vpb):
-        packed = packed | (c3[:, :, s] << jnp.uint32(s * bits))
-    o_ref[...] = packed.astype(jnp.uint8)
+    # pack: code at column (b*vpb + s) lands in byte b, bit-slot s
+    pmat_ref, o_ref = refs
+    w = packing.chunk_elems(bits)
+    for k in range(cols // w):
+        packed = packing.pack_chunk(c[:, k * w:(k + 1) * w], pmat_ref[...])
+        o_ref[:, k * packing.LANES:(k + 1) * packing.LANES] = (
+            packed.astype(jnp.uint8))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "stochastic", "block_rows",
@@ -104,16 +111,23 @@ def encode(x2d: jax.Array, B: jax.Array, seed: jax.Array, *, bits: int,
                                stochastic=stochastic, ncols=cols)
     seed_base = jnp.stack([jnp.asarray(seed, jnp.uint32).reshape(()),
                            jnp.asarray(idx_base, jnp.uint32).reshape(())])
+    in_specs = [
+        pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
+        pl.BlockSpec((2,), lambda i, j: (0,)),   # [seed, idx_base] (repl.)
+        pl.BlockSpec((1,), lambda i, j: (0,)),   # B    (replicated)
+    ]
+    args = [x2d, seed_base, jnp.asarray(B, jnp.float32).reshape(1)]
+    if vpb > 1:
+        # constant block index: fetched into VMEM once for the whole grid
+        pmat = packing.pack_matrix(bits)
+        in_specs.append(pl.BlockSpec(pmat.shape, lambda i, j: (0, 0)))
+        args.append(pmat)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),   # [seed, idx_base] (repl.)
-            pl.BlockSpec((1,), lambda i, j: (0,)),   # B    (replicated)
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, block_cols // vpb),
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, cols // vpb), jnp.uint8),
         interpret=interpret,
-    )(x2d, seed_base, jnp.asarray(B, jnp.float32).reshape(1))
+    )(*args)

@@ -5,8 +5,8 @@ dispatching to the Pallas kernels (``interpret=True`` automatically off-TPU so
 the same call validates on CPU), and restoring the caller's layout.
 
 The packed layout matches ``core.quantizers.pack_codes`` (pack along the last
-axis, zero-padded to the values-per-byte boundary) so payload byte accounting
-is identical between the kernel and pure-jnp paths.
+axis, zero-padded to the values-per-byte boundary; ``core/packing.py``) so
+payload byte accounting is identical between the kernel and pure-jnp paths.
 """
 from __future__ import annotations
 
@@ -15,9 +15,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.core.quantizers import QuantSpec, packed_last_dim
+from repro.core.quantizers import QuantSpec, pack_codes, unpack_codes
 from repro.kernels import moniqua_decode as _dec
 from repro.kernels import moniqua_decode_reduce as _dr
 from repro.kernels import moniqua_encode as _enc
@@ -99,13 +99,16 @@ def moniqua_encode_jnp(x: jax.Array, B: jax.Array, spec: QuantSpec,
     """Pure-jnp encode, bit-identical to :func:`moniqua_encode`.
 
     Uses the same padded tile layout so the counter-based hash draws the same
-    uniform per element as the kernel — the CommEngine jnp backend.
+    uniform per element as the kernel — the CommEngine jnp backend.  Packs
+    with ``pack_codes``, the kernels' matrix-unit packing.
     """
     vpb = spec.values_per_byte
     x2d, n, lead_shape, n_last, pad = _encode_layout(x, vpb)
-    p = kref.encode_ref(x2d, B, spec.bits, spec.stochastic, seed,
-                        idx_base=idx_base)
-    p = p.reshape(-1)[: n // vpb]
+    idx = (jnp.asarray(idx_base, jnp.uint32)
+           + jnp.arange(x2d.size, dtype=jnp.uint32).reshape(x2d.shape))
+    codes = kref.codes_ref(x2d, B, spec.bits, spec.stochastic,
+                           jnp.asarray(seed, jnp.uint32), idx)
+    p = pack_codes(codes, spec.bits).reshape(-1)[: n // vpb]
     return p.reshape(*lead_shape, (n_last + pad) // vpb)
 
 
@@ -206,31 +209,71 @@ def moniqua_decode_reduce_jnp(p_self: jax.Array, p_nbrs: jax.Array,
 # ``reshape(-1)``); applied directly to a stacked ``[n, ...]`` leaf that
 # would cross the (sharded) worker axis — XLA could insert resharding
 # around the encode/decode, and the counter-hash element index would differ
-# per worker, breaking Supp. C's shared randomness.  These wrappers vmap
-# the layout over axis 0 instead: each worker tiles its own slice with
-# element indices 0..d-1 and the SAME seed, so (a) the only cross-worker
-# traffic left in a CommEngine round is the packed collective-permute and
-# (b) every worker draws identical rounding uniforms per element (Supp. C).
+# per worker, breaking Supp. C's shared randomness.  These wrappers apply
+# the layout to each worker's slice of axis 0 instead: each worker tiles its
+# own slice with element indices 0..d-1 and the SAME seed, so (a) the only
+# cross-worker traffic left in a CommEngine round is the packed
+# collective-permute and (b) every worker draws identical rounding uniforms
+# per element (Supp. C).
+#
+# ``worker_axes`` names the mesh axes the worker dim is sharded over (one
+# worker per device on a worker mesh, entered with ``jax.set_mesh``).  The
+# per-worker loop then runs under ``shard_map`` on those axes: each device
+# launches the kernels on its own workers, and no custom call is left for
+# XLA to partition (it cannot partition a Mosaic kernel: it either refuses
+# or all-gathers the f32 operands onto every device).
 # ---------------------------------------------------------------------------
+
+def _per_worker(fn, worker_axes: tuple, in_axes: tuple, *args):
+    """``fn`` on each worker's slice of ``args`` (worker dim ``in_axes[i]``,
+    ``None`` = shared), stacked on a new axis 0; under ``shard_map`` over
+    ``worker_axes`` when given.
+
+    A Python loop over workers, not ``vmap``: batching the tile reshapes
+    leaves XLA's TPU compiler relayouts of ``[n, D]`` arrays with ``n`` on
+    the sublanes, whose compile time grows with ``D`` (over two minutes at
+    the 1.2e8 elements of xlstm-125m, against seconds for the loop).
+    """
+    def local(*xs):
+        n = next(x.shape[a] for x, a in zip(xs, in_axes) if a is not None)
+        return jnp.stack([
+            fn(*(x if a is None else jax.lax.index_in_dim(x, w, a, False)
+                 for x, a in zip(xs, in_axes)))
+            for w in range(n)])
+    if not worker_axes:
+        return local(*args)
+    specs = tuple(P() if a is None else P(*([None] * a), tuple(worker_axes))
+                  for a in in_axes)
+    return jax.shard_map(local, in_specs=specs,
+                         out_specs=P(tuple(worker_axes)),
+                         check_vma=False)(*args)
+
 
 def moniqua_encode_stacked(x: jax.Array, B, spec: QuantSpec,
                            seed: jax.Array, *, backend: str,
-                           idx_base: jax.Array | int = 0) -> jax.Array:
+                           idx_base: jax.Array | int = 0,
+                           worker_axes: tuple = ()) -> jax.Array:
     """Encode a stacked ``[n, ...]`` leaf with per-worker tile layout.
 
     ``idx_base`` is shared by every worker slice (the counter index never
     depends on the worker position — Supp. C shared randomness).
     """
     if backend == "pallas":
-        return jax.vmap(lambda xi: moniqua_encode(
-            xi, B, spec, None, seed=seed, idx_base=idx_base))(x)
-    return jax.vmap(lambda xi: moniqua_encode_jnp(
-        xi, B, spec, seed, idx_base=idx_base))(x)
+        def fn(xi, b, s):
+            return moniqua_encode(xi, b, spec, None, seed=s,
+                                  idx_base=idx_base)
+    else:
+        def fn(xi, b, s):
+            return moniqua_encode_jnp(xi, b, spec, s, idx_base=idx_base)
+    return _per_worker(fn, worker_axes, (0, None, None), x,
+                       jnp.asarray(B, jnp.float32),
+                       jnp.asarray(seed, jnp.uint32))
 
 
 def moniqua_decode_reduce_stacked(p_self: jax.Array, p_nbrs: jax.Array,
                                   y: jax.Array, B, weights, spec: QuantSpec,
-                                  *, backend: str) -> jax.Array:
+                                  *, backend: str,
+                                  worker_axes: tuple = ()) -> jax.Array:
     """Fused decode-reduce over a stacked leaf, tiled per worker.
 
     ``p_self``/``y`` carry the worker axis at 0; ``p_nbrs`` stacks the
@@ -239,8 +282,9 @@ def moniqua_decode_reduce_stacked(p_self: jax.Array, p_nbrs: jax.Array,
     """
     fn = (moniqua_decode_reduce if backend == "pallas"
           else moniqua_decode_reduce_jnp)
-    return jax.vmap(lambda ps, pn, yi: fn(ps, pn, yi, B, weights, spec),
-                    in_axes=(0, 1, 0))(p_self, p_nbrs, y)
+    return _per_worker(lambda ps, pn, yi, b: fn(ps, pn, yi, b, weights, spec),
+                       worker_axes, (0, 1, 0, None), p_self, p_nbrs, y,
+                       jnp.asarray(B, jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -258,8 +302,8 @@ def moniqua_decode_reduce_stacked(p_self: jax.Array, p_nbrs: jax.Array,
 
 def moniqua_encode_chunk(flat: jax.Array, offset: int, size: int, B,
                          spec: QuantSpec, seed: jax.Array, *,
-                         backend: str, idx_base: Optional[int] = None
-                         ) -> jax.Array:
+                         backend: str, idx_base: Optional[int] = None,
+                         worker_axes: tuple = ()) -> jax.Array:
     """Encode the window ``flat[:, offset:offset+size]`` of a stacked flat
     buffer, with globally-indexed rounding uniforms (``idx_base=offset``).
 
@@ -271,25 +315,27 @@ def moniqua_encode_chunk(flat: jax.Array, offset: int, size: int, B,
     win = jax.lax.slice_in_dim(flat, offset, offset + size, axis=1)
     return moniqua_encode_stacked(win, B, spec, seed, backend=backend,
                                   idx_base=offset if idx_base is None
-                                  else idx_base)
+                                  else idx_base, worker_axes=worker_axes)
 
 
 def moniqua_decode_reduce_chunk(p_self: jax.Array, p_nbrs: jax.Array,
                                 flat: jax.Array, offset: int, size: int, B,
                                 weights, spec: QuantSpec, *,
-                                backend: str) -> jax.Array:
+                                backend: str,
+                                worker_axes: tuple = ()) -> jax.Array:
     """Fused decode-reduce of one chunk's payloads against the matching
     window of the local flat buffer (decode draws no randomness, so only
     the window slice matters — no idx_base needed)."""
     win = jax.lax.slice_in_dim(flat, offset, offset + size, axis=1)
     return moniqua_decode_reduce_stacked(p_self, p_nbrs, win, B, weights,
-                                         spec, backend=backend)
+                                         spec, backend=backend,
+                                         worker_axes=worker_axes)
 
 
 # Reference-path conveniences used by MoniquaCodec(use_pallas=True)
 
 def moniqua_unpack_value(packed, B, spec: QuantSpec, last_dim: int):
-    codes = kref.unpack_ref(packed, spec.bits)[..., :last_dim]
+    codes = unpack_codes(packed, spec.bits, last_dim)
     return ((codes.astype(jnp.float32) + 0.5) / spec.levels - 0.5) * B
 
 

@@ -2,8 +2,11 @@
 
 These define the *exact* semantics the Pallas kernels must reproduce
 (bitwise, including the in-kernel hash RNG), and are what the tests
-``assert_allclose`` against.  They are also the fallback path used on
-non-TPU backends.
+``assert_allclose`` against.  The packing here is written independently
+of ``core/packing.py`` (plain shifts, test-only: the lane-splitting
+reshape does not suit the TPU), so a kernel test never compares the
+packing step against itself.  The jnp backend shares only
+:func:`codes_ref` and :func:`cmod`.
 
 RNG: stochastic rounding uses a counter-based murmur3-finalizer hash of
 ``(seed, flat_element_index)`` so that (a) the same element gets the same
@@ -42,7 +45,8 @@ def codes_ref(x: jax.Array, B, bits: int, stochastic: bool,
 
 
 def pack_ref(codes: jax.Array, bits: int) -> jax.Array:
-    """Pack codes into uint8 along the last axis (must be divisible)."""
+    """Pack codes into uint8 along the last axis (must be divisible):
+    code ``b*vpb + j`` in byte ``b``, bits ``[j*bits, (j+1)*bits)``."""
     if bits == 8:
         return codes.astype(jnp.uint8)
     vpb = 8 // bits
@@ -54,6 +58,7 @@ def pack_ref(codes: jax.Array, bits: int) -> jax.Array:
 
 
 def unpack_ref(packed: jax.Array, bits: int) -> jax.Array:
+    """Inverse of :func:`pack_ref`."""
     if bits == 8:
         return packed
     vpb = 8 // bits

@@ -36,8 +36,7 @@ from repro.analysis import roofline as RL
 from repro.configs import assigned_archs, get_config
 from repro.configs.base import get_input_shape
 from repro.launch import dryrun as DR
-from repro.launch.mesh import (make_production_mesh, mesh_context,
-                               mesh_shape_dict)
+from repro.launch.mesh import make_production_mesh, mesh_shape_dict
 from repro.models.model_factory import build_model
 from repro.models.sharding import ShardingRules
 from repro.train import train_step as TS
@@ -55,7 +54,7 @@ def _probe(arch: str, shape, mesh, ms, depth: int, algo: str, bits: int):
     model = build_model(cfg)
     n_workers = TS.n_workers_for(cfg, rules, ms)
     from repro.models import sharding as SH
-    with mesh_context(mesh), SH.constraint_context(rules, ms):
+    with jax.set_mesh(mesh), SH.constraint_context(rules, ms):
         if shape.kind == "train":
             lowered = DR._lower_train(model, shape, mesh, ms, rules,
                                       n_workers, algo, bits)
@@ -64,7 +63,7 @@ def _probe(arch: str, shape, mesh, ms, depth: int, algo: str, bits: int):
         else:
             lowered = DR._lower_decode(model, shape, mesh, ms, rules)
         compiled = lowered.compile()
-    ca = RL.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     stats = RL.parse_collectives(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)), stats)

@@ -39,7 +39,7 @@ from repro.core.quantizers import QuantSpec
 from repro.core.theta import ThetaSchedule
 from repro.core.topology import ring
 from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               mesh_context, mesh_shape_dict)
+                               mesh_shape_dict)
 from repro.models.model_factory import build_model
 from repro.models.sharding import ShardingRules
 from repro.optim.sgd import SGDConfig
@@ -129,7 +129,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         n_workers = TS.n_workers_for(cfg, rules, ms)
 
         from repro.models import sharding as SH
-        with mesh_context(mesh), SH.constraint_context(rules, ms):
+        with jax.set_mesh(mesh), SH.constraint_context(rules, ms):
             with span("dryrun.lower"):
                 if shape.kind == "train":
                     lowered = _lower_train(model, shape, mesh, ms, rules,
@@ -145,7 +145,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         mem = compiled.memory_analysis()
         print(f"[{arch} x {shape_name} x {mesh_name}] memory_analysis:",
               mem)
-        ca = RL.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         print(f"[{arch} x {shape_name} x {mesh_name}] cost_analysis: "
               f"flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e}")
@@ -213,12 +213,14 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def _hyper(cfg, n_workers, algo, bits, wire="moniqua", comm_backend="auto",
-           comm_path="auto", chunks=1, tiers=1, telemetry=False):
+           comm_path="auto", chunks=1, tiers=1, telemetry=False,
+           worker_axes=()):
     topo = ring(n_workers)
     spec = QuantSpec(bits=bits, stochastic=bits > 1)
     return AlgoHyper(topo=topo, codec=MoniquaCodec(spec), theta=2.0,
                      wire=wire, backend=comm_backend, path=comm_path,
-                     chunks=chunks, tiers=tiers, telemetry=telemetry)
+                     chunks=chunks, tiers=tiers, telemetry=telemetry,
+                     worker_axes=worker_axes)
 
 
 def _sim_predict(scenario_name: str, model, hp, n_workers: int, roof):
@@ -256,7 +258,8 @@ def _lower_train(model, shape, mesh, ms, rules, n_workers, algo_name, bits,
                  chunks=1, tiers=1, telemetry=False):
     algo = get_algorithm(algo_name)
     hp = _hyper(model.cfg, n_workers, algo_name, bits, wire, comm_backend,
-                comm_path, chunks, tiers, telemetry)
+                comm_path, chunks, tiers, telemetry,
+                rules.worker_axes if tiers <= 1 else ())
     tcfg = TS.TrainStepConfig(algo=algo_name, sgd=SGDConfig(), lr=0.1,
                               theta=ThetaSchedule(mode="constant", value=2.0))
     step = TS.make_train_step(model, hp, tcfg)

@@ -1,41 +1,29 @@
 """Production mesh factories.
 
 Functions, not module-level constants: importing this module never touches
-jax device state (required for smoke tests that must see 1 device).
-
-Version compatibility: ``AxisType`` / ``axis_types=`` and the ambient-mesh
-setter ``jax.set_mesh`` only exist in newer jax releases.  ``_make_mesh`` and
-``mesh_context`` paper over both so the same call sites run on the pinned
-jax (0.4.x: ``Mesh`` is its own context manager, meshes are untyped) and on
-current jax (explicit ``AxisType.Auto`` axes, ``jax.set_mesh``).
+jax device state (required for smoke tests that must see 1 device).  Every
+mesh has explicit ``AxisType.Auto`` axes; enter one with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: typed mesh axes
-    from jax.sharding import AxisType
-except ImportError:  # pinned jax 0.4.x: untyped meshes only
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
+def make_worker_mesh(devices=None):
+    """One decentralized worker per device, for the devices present.
 
-    Newer jax wants ``jax.set_mesh(mesh)``; on 0.4.x the ``Mesh`` object is
-    itself a context manager with the same scoping semantics.
+    Axes ``("data", "model")`` with ``model`` of size 1, so the
+    decentralized ``ShardingRules`` put the stacked worker axis on
+    ``data`` and every tensor-parallel dim resolves to a trivial axis.
     """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    devices = list(jax.devices() if devices is None else devices)
+    return _make_mesh((len(devices), 1), ("data", "model"), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,6 +4,11 @@ CPU-scale (default): reduced config, workers as an array axis —
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --algo moniqua --workers 8 --bits 8 --steps 50
 
+One worker per device present (e.g. a four-chip host; --workers is the
+device count) —
+    PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m \
+        --mesh workers --full-size --seq 1024 --batch 16
+
 Production mesh (requires a real fleet or forced host devices) —
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-20b \
         --mesh production --shape train_4k --full-size
@@ -31,7 +36,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
-    ap.add_argument("--mesh", choices=["cpu", "production"], default="cpu")
+    ap.add_argument("--mesh", choices=["cpu", "workers", "production"],
+                    default="cpu")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--shape", default=None,
                     help="assigned input shape name (production mesh)")
@@ -42,8 +48,12 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config
     from repro.configs.base import InputShape, get_input_shape
+    from repro.launch import compile_cache
     from repro.models.model_factory import build_model
+    from repro.models.sharding import ShardingRules
     from repro.train.trainer import Trainer, TrainerConfig
+
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if not args.full_size:
@@ -51,9 +61,14 @@ def main(argv=None) -> int:
     model = build_model(cfg)
 
     mesh = rules = None
-    if args.mesh == "production":
+    if args.mesh == "workers":
+        from repro.launch.mesh import make_worker_mesh
+        mesh = make_worker_mesh()
+        rules = ShardingRules("decentralized")
+        args.workers = mesh.devices.size
+        shape = InputShape("cli", args.seq, args.batch, "train")
+    elif args.mesh == "production":
         from repro.launch.mesh import make_production_mesh
-        from repro.models.sharding import ShardingRules
         mesh = make_production_mesh(multi_pod=args.multi_pod)
         rules = ShardingRules(cfg.dist_mode, multi_pod=args.multi_pod)
         shape = get_input_shape(args.shape or "train_4k")

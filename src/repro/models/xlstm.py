@@ -73,7 +73,11 @@ def _mlstm_scan_chunks(q, k, v, log_f, log_i, chunk):
     # intra-chunk: att[t,s] = exp(F_t - F_s + li_s) * (q_t . k_s), s <= t
     expo = F[:, :, :, None, :] - F[:, :, None, :, :] + li[:, :, None, :, :]
     tri = jnp.tril(jnp.ones((c, c), bool))
-    w = jnp.where(tri[None, None, :, :, None], jnp.exp(expo), 0.0)  # [B,nc,t,s,H]
+    # mask before the exp: above the diagonal F_t - F_s > 0 grows with the
+    # chunk and overflows f32 at chunk 128, and a masked exp(inf) still
+    # sends inf * 0 = NaN into the backward pass
+    w = jnp.exp(jnp.where(tri[None, None, :, :, None], expo,
+                          -jnp.inf))                     # [B,nc,t,s,H]
     qk = jnp.einsum("bnthd,bnshd->bntsh", qc, kc).astype(jnp.float32)
     aw = w * qk / math.sqrt(D)
     y_intra = jnp.einsum("bntsh,bnshd->bnthd", aw.astype(q.dtype), vc)

@@ -1,9 +1,12 @@
 """Host-side training loop tying pipeline, step function, and checkpoints.
 
 Works at two scales with the same code path:
-  * experiment scale: 1 CPU device, worker dim is a plain array axis;
-  * production scale: mesh provided, state/batch placed with NamedShardings
-    from train_step.state_pspecs / batch_pspecs.
+  * experiment scale: 1 device, worker dim is a plain array axis;
+  * mesh provided (e.g. ``launch.mesh.make_worker_mesh``: one worker per
+    chip): state and batch are placed with NamedShardings from
+    train_step.state_pspecs / batch_pspecs, the step is jitted with those
+    in/out shardings and traced under ``jax.set_mesh``, and the gossip
+    engine runs its codec kernels per device on the worker axes.
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.comm import gossip
 from repro.core.algorithms import AlgoHyper, get_algorithm
@@ -71,7 +75,7 @@ class TrainerConfig:
                                 #   (recorded; enforced by sim/faults.py)
 
 
-def build_hyper(tc: TrainerConfig) -> AlgoHyper:
+def build_hyper(tc: TrainerConfig, worker_axes: tuple = ()) -> AlgoHyper:
     from repro.core.quantizers import QuantSpec
     topo = get_topology(tc.topology, tc.n_workers)
     if tc.slack < 1.0:
@@ -83,7 +87,7 @@ def build_hyper(tc: TrainerConfig) -> AlgoHyper:
                      path=tc.comm_path, chunks=tc.chunks, overlap=tc.overlap,
                      warmup=tc.warmup, telemetry=tc.telemetry,
                      tiers=tc.tiers, presence=presence,
-                     deadline=tc.deadline)
+                     deadline=tc.deadline, worker_axes=tuple(worker_axes))
 
 
 class Trainer:
@@ -91,7 +95,12 @@ class Trainer:
                  mesh: Optional[Mesh] = None,
                  rules: Optional[ShardingRules] = None):
         self.model, self.tc = model, tc
-        self.hp = build_hyper(tc)
+        if mesh is not None and rules is None:
+            raise ValueError("a mesh needs its ShardingRules")
+        # the tiered round stages its own mesh-axis collectives; only the
+        # single-tier round runs the codec kernels per device
+        self.hp = build_hyper(tc, rules.worker_axes
+                              if mesh is not None and tc.tiers <= 1 else ())
         self.algo = get_algorithm(tc.algo)
         from repro.core.theta import ThetaSchedule
         from repro.optim.sgd import SGDConfig
@@ -114,16 +123,32 @@ class Trainer:
         self.hp.engine().layout(abstract["params"])
         self.step_fn = TS.make_train_step(model, self.hp, self.tcfg)
         self.mesh = mesh
-        if mesh is not None:
-            assert rules is not None
-            mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-            sp = TS.state_pspecs(model, self.algo, self.hp, rules, mesh_shape,
-                                 tc.n_workers)
-            self._state_sh = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), sp)
+        if mesh is None:
             self.jstep = jax.jit(self.step_fn, donate_argnums=(0,))
-        else:
-            self.jstep = jax.jit(self.step_fn, donate_argnums=(0,))
+            return
+        mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+        sp = TS.state_pspecs(model, self.algo, self.hp, rules, mesh_shape,
+                             tc.n_workers)
+        self._state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), sp)
+        bp = TS.batch_pspecs(jax.eval_shape(self.pipeline.worker_batch, 0),
+                             rules, mesh_shape)
+        self._batch_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), bp)
+        self.jstep = jax.jit(
+            self.step_fn, donate_argnums=(0,),
+            in_shardings=(self._state_sh, self._batch_sh),
+            out_shardings=(self._state_sh, NamedSharding(mesh, P())))
+
+    def batch(self, k: int) -> PyTree:
+        """The stacked batch of global step ``k``, placed on the mesh."""
+        b = self.pipeline.worker_batch(k)
+        return b if self.mesh is None else jax.device_put(b, self._batch_sh)
+
+    def step(self, state: PyTree, batch: PyTree):
+        """One jitted train step (under the worker mesh, if any)."""
+        if self.mesh is None:
+            return self.jstep(state, batch)
+        with jax.set_mesh(self.mesh):
+            return self.jstep(state, batch)
 
     def init_state(self) -> PyTree:
         key = jax.random.PRNGKey(self.tc.seed)
@@ -175,12 +200,12 @@ class Trainer:
         t0 = time.time()
         try:
             for k in range(k0, k0 + tc.steps):
-                batch = self.pipeline.worker_batch(k)
+                batch = self.batch(k)
                 if rec is not None:
                     with rec.span("train.step", tid="train", step=k):
-                        state, metrics = self.jstep(state, batch)
+                        state, metrics = self.step(state, batch)
                 else:
-                    state, metrics = self.jstep(state, batch)
+                    state, metrics = self.step(state, batch)
                 if (k - k0) % tc.log_every == 0 or k == k0 + tc.steps - 1:
                     # drain the whole metrics dict in ONE host transfer —
                     # per-scalar float() round-trips device-synced once per

@@ -143,3 +143,22 @@ def test_prefill_last_only_serving_semantics(arch):
     full = model.prefill_logits(params, batch, last_only=False)
     np.testing.assert_allclose(np.asarray(last[:, 0]),
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
+
+
+def test_mlstm_gradient_finite_at_published_chunk():
+    """xlstm-125m's chunk of 128: above the diagonal the intra-chunk decay
+    exponent exceeds f32's exp range, and masking after the exp turned the
+    overflow into NaN gradients on the first step."""
+    from repro.models.xlstm import _mlstm_scan_chunks
+    B, S, H, D = 1, 128, 2, 8
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(kk, (B, S, H, D)) for kk in (k1, k2, k3))
+    log_f = jnp.full((B, S, H), -1.0)            # sigmoid gate of about -0.5
+    log_i = jnp.full((B, S, H), -0.5)
+
+    def loss(q, log_f):
+        return jnp.sum(_mlstm_scan_chunks(q, k, v, log_f, log_i, 128))
+
+    gq, gf = jax.grad(loss, argnums=(0, 1))(q, log_f)
+    assert np.isfinite(np.asarray(gq)).all()
+    assert np.isfinite(np.asarray(gf)).all()

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import packing
 from repro.core.quantizers import QuantSpec
 from repro.kernels import moniqua_decode as DEC
 from repro.kernels import moniqua_encode as ENC
@@ -108,6 +109,48 @@ def test_ops_self_mode_matches_core():
     # reconstruct with ref to compare
     ref = R.decode_self_ref(p, x, B, 8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def _np_pack(codes: np.ndarray, bits: int) -> np.ndarray:
+    """The wire layout stated independently of ``core/packing.py``: code
+    ``b*vpb + j`` of the last axis sits in byte ``b``, bits
+    ``[j*bits, (j+1)*bits)``."""
+    vpb = 8 // bits
+    c = codes.astype(np.uint32).reshape(*codes.shape[:-1], -1, vpb)
+    return sum(c[..., j] << (j * bits) for j in range(vpb)).astype(np.uint8)
+
+
+@pytest.mark.parametrize("bits", BITS)
+def test_packed_layout_round_trip(bits):
+    """Pins the packed layout at every width: the kernel's packed tile is
+    numpy's interleave of the reference codes, and both unpack paths (the
+    jnp ``unpack_codes`` and the kernels' in-tile chunk unpack) give the
+    codes back — every code value, in every bit slot."""
+    from repro.core.quantizers import pack_codes, unpack_codes
+    from repro.kernels import moniqua_decode_reduce as DR
+    x = _tile(seed=6)
+    idx = jnp.arange(x.size, dtype=jnp.uint32).reshape(x.shape)
+    codes = np.asarray(R.codes_ref(x, 4.0, bits, True, jnp.uint32(13), idx))
+    p_k = ENC.encode(x, jnp.float32(4.0), jnp.uint32(13), bits=bits,
+                     stochastic=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(p_k), _np_pack(codes, bits))
+    # all 2**bits code values in every slot, on an unaligned length
+    full = np.arange(2 ** bits * 8 // bits * 3 + 5) % 2 ** bits
+    full = np.resize(full, (2, full.size))
+    np.testing.assert_array_equal(
+        np.asarray(pack_codes(jnp.asarray(full, jnp.uint8), bits)),
+        _np_pack(np.pad(full, [(0, 0), (0, -full.shape[1] % (8 // bits))]),
+                 bits))
+    back = unpack_codes(jnp.asarray(_np_pack(codes, bits)), bits,
+                        codes.shape[-1])
+    np.testing.assert_array_equal(np.asarray(back), codes)
+    B = jnp.float32(4.0)
+    umat = None if bits == 8 else packing.unpack_matrix(bits)
+    chunk = DR.tile_values(p_k[:, :packing.LANES], bits, B, umat)
+    np.testing.assert_array_equal(
+        np.asarray(chunk),
+        np.asarray(DR.dequant(jnp.asarray(
+            codes[:, :chunk.shape[1]], jnp.float32), bits, B)))
 
 
 def test_kernel_rejects_untied_shapes():
