@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 PyTree = Any
 
 
@@ -194,31 +196,33 @@ class BucketLayout:
         while consecutive in-place DUS fusions stay at copy speed.
         """
         leaves = self.treedef.flatten_up_to(X)
-        buf = jnp.zeros((self.n_workers, self.padded_elems),
-                        self.stage_dtype)
-        for leaf, s in zip(leaves, self.slots):
-            seg = jnp.reshape(leaf, (self.n_workers, s.rows, s.last))
-            seg = seg.astype(self.stage_dtype)
-            if s.last_padded != s.last:
-                seg = jnp.pad(seg, ((0, 0), (0, 0),
-                                    (0, s.last_padded - s.last)))
-            buf = jax.lax.dynamic_update_slice(
-                buf, seg.reshape(self.n_workers, s.padded_size),
-                (0, s.offset))
+        with obs_trace.named_phase("comm.stage"):
+            buf = jnp.zeros((self.n_workers, self.padded_elems),
+                            self.stage_dtype)
+            for leaf, s in zip(leaves, self.slots):
+                seg = jnp.reshape(leaf, (self.n_workers, s.rows, s.last))
+                seg = seg.astype(self.stage_dtype)
+                if s.last_padded != s.last:
+                    seg = jnp.pad(seg, ((0, 0), (0, 0),
+                                        (0, s.last_padded - s.last)))
+                buf = jax.lax.dynamic_update_slice(
+                    buf, seg.reshape(self.n_workers, s.padded_size),
+                    (0, s.offset))
         return buf
 
     def unflatten(self, flat: jax.Array) -> PyTree:
         """Inverse of :func:`flatten`: slice segments, drop row padding,
         restore each leaf's shape and dtype."""
         out = []
-        for s in self.slots:
-            seg = jax.lax.slice_in_dim(flat, s.offset, s.offset + s.padded_size,
-                                       axis=1)
-            if s.last_padded != s.last:
-                seg = seg.reshape(self.n_workers, s.rows, s.last_padded)
-                seg = seg[..., :s.last]
-            out.append(seg.reshape((self.n_workers,) + s.shape)
-                       .astype(s.dtype))
+        with obs_trace.named_phase("comm.scatter"):
+            for s in self.slots:
+                seg = jax.lax.slice_in_dim(flat, s.offset,
+                                           s.offset + s.padded_size, axis=1)
+                if s.last_padded != s.last:
+                    seg = seg.reshape(self.n_workers, s.rows, s.last_padded)
+                    seg = seg[..., :s.last]
+                out.append(seg.reshape((self.n_workers,) + s.shape)
+                           .astype(s.dtype))
         return self.treedef.unflatten(out)
 
 

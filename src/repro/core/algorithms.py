@@ -40,6 +40,7 @@ from repro.core.quantizers import QuantSpec
 from repro.core import topology
 from repro.core.topology import Topology
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -133,7 +134,9 @@ class AlgoHyper:
 # ---------------------------------------------------------------------------
 
 def _sgd(X: PyTree, g: PyTree, alpha) -> PyTree:
-    return jax.tree.map(lambda x, d: (x - alpha * d).astype(x.dtype), X, g)
+    with obs_trace.named_phase("train.optimizer"):
+        return jax.tree.map(lambda x, d: (x - alpha * d).astype(x.dtype),
+                            X, g)
 
 
 def _norm_quantize(v: jax.Array, bits: int, key: Optional[jax.Array],

@@ -25,7 +25,13 @@ def enable() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is already jax's cache
     directory and is left as it is; otherwise the cache goes to
     ``<checkout>/.jax_cache``.
+
+    The key holds the program's metadata too.  A profiler maps device ops
+    to the program's named scopes through the executable's ``op_name``
+    metadata, and without it in the key a program whose scopes changed
+    would load an executable compiled with the old ones.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(ENV)
     if not path:
         path = DEFAULT_DIR

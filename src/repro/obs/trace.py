@@ -2,16 +2,18 @@
 
 Two timing domains, one file format:
 
-* **Inside jit** — the engine wraps encode / permute / decode-reduce in
-  ``jax.named_scope("comm.encode" | "comm.permute" | "comm.decode_reduce"
-  | "comm.telemetry")`` so the phases are attributed in XLA/profiler
-  output; :func:`trace_annotation` adds a ``jax.profiler``
-  TraceAnnotation when the host-side profiler is active.
-* **On the host** — :class:`SpanRecorder` is a zero-dependency span
-  recorder (``with rec.span("step", tid="train"): ...``) whose events
-  export to the Chrome trace event format (``ph: "X"`` complete events,
-  microsecond timestamps) that Perfetto / ``chrome://tracing`` open
-  directly.
+* **Inside jit** — the train step and the gossip round open the named
+  scopes listed in :data:`SCOPES` (``train.grad``, ``train.optimizer``,
+  ``comm.stage``, ``comm.encode``, ...), so the device ops of a step are
+  attributed to them in XLA/profiler output (the HLO ``op_name``
+  metadata).
+* **On the host** — :func:`span` opens a ``jax.profiler.TraceAnnotation``,
+  so the span sits on the profiler's clock beside the device ops when a
+  profiler trace is running, and records into a :class:`SpanRecorder`
+  when one is given (``with rec.span("train.fetch", tid="train"): ...``).
+  The recorder's events export to the Chrome trace event format
+  (``ph: "X"`` complete events, microsecond timestamps) that Perfetto /
+  ``chrome://tracing`` open directly.
 
 :func:`sim_trace_to_chrome` renders a ``repro.sim`` event timeline
 (:class:`~repro.sim.events.SimTrace`) in the same format: one track per
@@ -30,14 +32,18 @@ from typing import Any, Dict, Iterable, List, Optional
 
 TRACE_SCHEMA = "repro.obs.trace/v1"
 
-# the named_scope labels CommEngine.mix uses for the phases of one round
-COMM_PHASES = ("comm.encode", "comm.permute", "comm.decode_reduce",
-               "comm.telemetry")
+# every named scope the program opens inside jit: the train step's
+# gradient (forward and backward) and optimizer, the gossip round's staging
+# copies in and out of the flat buffer and its phases, the serving steps
+SCOPES = ("train.grad", "train.optimizer",
+          "comm.stage", "comm.scatter", "comm.encode", "comm.permute",
+          "comm.decode_reduce", "comm.intra_reduce", "comm.telemetry",
+          "serve.prefill", "serve.decode")
 
 
 def named_phase(name: str):
-    """``jax.named_scope`` for a gossip phase (compile-time metadata only —
-    zero runtime cost, and no effect on the lowered math)."""
+    """``jax.named_scope`` for one of :data:`SCOPES` (compile-time metadata
+    only — zero runtime cost, and no effect on the lowered math)."""
     import jax
     return jax.named_scope(name)
 
@@ -48,7 +54,7 @@ def chunk_phase(phase: str, chunk: Optional[int] = None,
 
     A chunk-pipelined round (``CommEngine.round_plan``) runs each phase K
     times; labelling the scopes ``comm.encode/chunk03of08`` keeps the base
-    ``COMM_PHASES`` name as a prefix (existing phase-based tooling still
+    ``SCOPES`` name as a prefix (existing phase-based tooling still
     aggregates by prefix) while the profiler timeline shows the skewed
     encode(i+1)/permute(i)/decode(i-1) ladder span by span.  A barrier
     round (``chunk=None`` or a single chunk) keeps the plain phase label.
@@ -60,27 +66,39 @@ def chunk_phase(phase: str, chunk: Optional[int] = None,
     return named_phase(f"{phase}/{suffix}")
 
 
-def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available (host-side; shows up
-    in profiler timelines), otherwise a no-op context."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler not available
-        return contextlib.nullcontext()
-
-
 # ---------------------------------------------------------------------------
-# Host-side span recorder.
+# Host-side spans.
 # ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def span(name: str, rec: Optional["SpanRecorder"] = None, tid: str = "host",
+         **args):
+    """A host span: always a ``jax.profiler.TraceAnnotation`` (on the
+    profiler's clock while a trace runs, about a microsecond otherwise),
+    and a ``{name, t0_s, dur_s, tid, args}`` event of ``rec`` when a
+    recorder is given."""
+    import jax
+    with jax.profiler.TraceAnnotation(name, **args):
+        if rec is None:
+            yield
+            return
+        t0 = rec.now()
+        try:
+            yield
+        finally:
+            rec.events.append({"name": name, "t0_s": t0,
+                               "dur_s": rec.now() - t0, "tid": tid,
+                               "args": dict(args)})
+
 
 class SpanRecorder:
     """Lightweight wall-clock span recorder (``time.perf_counter`` based).
 
     Spans are dicts ``{name, t0_s, dur_s, tid, args}`` with times relative
-    to the recorder's creation; ``to_chrome`` / ``save`` export them as a
-    Chrome trace, and ``repro.obs.runlog.RunLogWriter.spans_from`` copies
-    them into a JSONL run log for ``tools/obs_report.py``'s phase
+    to the recorder's creation; each span is also a profiler
+    TraceAnnotation (:func:`span`).  ``to_chrome`` / ``save`` export them
+    as a Chrome trace, and ``repro.obs.runlog.RunLogWriter.spans_from``
+    copies them into a JSONL run log for ``tools/obs_report.py``'s phase
     breakdown.
     """
 
@@ -91,15 +109,8 @@ class SpanRecorder:
     def now(self) -> float:
         return time.perf_counter() - self._t0
 
-    @contextlib.contextmanager
     def span(self, name: str, tid: str = "host", **args):
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.events.append({"name": name, "t0_s": t0,
-                                "dur_s": self.now() - t0, "tid": tid,
-                                "args": dict(args)})
+        return span(name, self, tid=tid, **args)
 
     def instant(self, name: str, tid: str = "host", **args) -> None:
         self.events.append({"name": name, "t0_s": self.now(), "dur_s": 0.0,

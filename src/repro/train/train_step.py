@@ -39,6 +39,7 @@ from repro.core.algorithms import AlgoHyper, Algorithm, get_algorithm
 from repro.core.theta import ThetaSchedule
 from repro.models.model_factory import Model
 from repro.models.sharding import ShardingRules, safe_pspec
+from repro.obs import trace as obs_trace
 from repro.optim import sgd as optim
 
 PyTree = Any
@@ -173,9 +174,14 @@ def make_train_step(model: Model, hp: AlgoHyper, tcfg: TrainStepConfig
         step, key = state["step"], state["key"]
         key, k_algo = jax.random.split(key)
 
-        losses, grads = jax.vmap(jax.value_and_grad(model.loss))(X, batch)
-        dirs, mom, g_inf_now = optim.direction(tcfg.sgd, grads, X, mom)
-        g_inf = jnp.maximum(0.9 * state["g_inf"], g_inf_now)
+        # forward and backward: JAX names the backward's ops under
+        # ``train.grad/.../transpose(jvp(...))``
+        with obs_trace.named_phase("train.grad"):
+            losses, grads = jax.vmap(jax.value_and_grad(model.loss))(X,
+                                                                     batch)
+        with obs_trace.named_phase("train.optimizer"):
+            dirs, mom, g_inf_now = optim.direction(tcfg.sgd, grads, X, mom)
+            g_inf = jnp.maximum(0.9 * state["g_inf"], g_inf_now)
 
         alpha = sched(step)
         theta = tcfg.theta(alpha, g_inf)

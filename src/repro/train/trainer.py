@@ -26,14 +26,10 @@ from repro.core.topology import get_topology
 from repro.data.pipeline import SyntheticLMPipeline
 from repro.models.model_factory import Model
 from repro.models.sharding import ShardingRules
+from repro.obs import trace as obs_trace
 from repro.train import train_step as TS
 
 PyTree = Any
-
-
-def _null_ctx():
-    import contextlib
-    return contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -138,17 +134,23 @@ class Trainer:
             in_shardings=(self._state_sh, self._batch_sh),
             out_shardings=(self._state_sh, NamedSharding(mesh, P())))
 
-    def batch(self, k: int) -> PyTree:
-        """The stacked batch of global step ``k``, placed on the mesh."""
-        b = self.pipeline.worker_batch(k)
-        return b if self.mesh is None else jax.device_put(b, self._batch_sh)
+    def batch(self, k: int, rec=None) -> PyTree:
+        """The stacked batch of global step ``k``, placed on the mesh.
+        The host spans go to ``rec`` (a ``SpanRecorder``) when given."""
+        with obs_trace.span("train.batch", rec, tid="train", step=k):
+            b = self.pipeline.worker_batch(k)
+            if self.mesh is None:
+                return b
+            with obs_trace.span("train.place", rec, tid="train"):
+                return jax.device_put(b, self._batch_sh)
 
-    def step(self, state: PyTree, batch: PyTree):
+    def step(self, state: PyTree, batch: PyTree, rec=None):
         """One jitted train step (under the worker mesh, if any)."""
-        if self.mesh is None:
-            return self.jstep(state, batch)
-        with jax.set_mesh(self.mesh):
-            return self.jstep(state, batch)
+        with obs_trace.span("train.dispatch", rec, tid="train"):
+            if self.mesh is None:
+                return self.jstep(state, batch)
+            with jax.set_mesh(self.mesh):
+                return self.jstep(state, batch)
 
     def init_state(self) -> PyTree:
         key = jax.random.PRNGKey(self.tc.seed)
@@ -190,8 +192,7 @@ class Trainer:
         history: List[Dict] = []
         rec = writer = None
         if tc.trace_path or tc.log_jsonl:
-            from repro.obs.trace import SpanRecorder
-            rec = SpanRecorder()
+            rec = obs_trace.SpanRecorder()
         if tc.log_jsonl:
             from repro.obs.runlog import RunLogWriter
             run_meta = dataclasses.asdict(tc)
@@ -200,18 +201,15 @@ class Trainer:
         t0 = time.time()
         try:
             for k in range(k0, k0 + tc.steps):
-                batch = self.batch(k)
-                if rec is not None:
-                    with rec.span("train.step", tid="train", step=k):
-                        state, metrics = self.step(state, batch)
-                else:
-                    state, metrics = self.step(state, batch)
+                state, metrics = self.step(state, self.batch(k, rec), rec)
                 if (k - k0) % tc.log_every == 0 or k == k0 + tc.steps - 1:
                     # drain the whole metrics dict in ONE host transfer —
                     # per-scalar float() round-trips device-synced once per
                     # metric per log point
-                    m = {kk: float(v)
-                         for kk, v in jax.device_get(metrics).items()}
+                    with obs_trace.span("train.fetch", rec, tid="train",
+                                        step=k):
+                        got = jax.device_get(metrics)
+                    m = {kk: float(v) for kk, v in got.items()}
                     m["step"] = k
                     m["wall"] = time.time() - t0
                     history.append(m)
@@ -224,10 +222,8 @@ class Trainer:
                 if (tc.checkpoint_path and tc.checkpoint_every
                         and (k + 1) % tc.checkpoint_every == 0):
                     meta = {"step": k + 1, "algo": tc.algo, "wire": tc.wire}
-                    ckpt_ctx = (rec.span("train.checkpoint", tid="train",
-                                         step=k + 1)
-                                if rec is not None else _null_ctx())
-                    with ckpt_ctx:
+                    with obs_trace.span("train.checkpoint", rec, tid="train",
+                                        step=k + 1):
                         # params-only artifact (the eval/restore surface)
                         ckpt.save(tc.checkpoint_path, state["params"], meta)
                         # ... plus the FULL state (momentum, WireState,

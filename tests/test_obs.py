@@ -321,6 +321,57 @@ def test_span_recorder_chrome_export_validates(tmp_path):
         assert TR.validate_chrome(json.load(f)) == []
 
 
+def _profiled_host_events(tmp_path, body):
+    """Names of the host events a profiler trace of ``body()`` holds."""
+    from jax.profiler import ProfileData
+    out = str(tmp_path / "prof")
+    with jax.profiler.trace(out):
+        body()
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(out) for f in fs
+             if f.endswith(".xplane.pb")]
+    assert len(paths) == 1
+    return [e.name.split("#")[0]
+            for p in ProfileData.from_file(paths[0]).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events]
+
+
+def test_span_recorder_spans_are_profiler_annotations(tmp_path):
+    """A recorder's span is on the profiler's clock (a TraceAnnotation host
+    event), and its Chrome export still validates."""
+    rec = TR.SpanRecorder()
+
+    def body():
+        with rec.span("obs.test.outer", tid="train", step=3):
+            with TR.span("obs.test.bare"):
+                jnp.ones(4).block_until_ready()
+
+    names = _profiled_host_events(tmp_path, body)
+    assert names.count("obs.test.outer") == 1
+    assert names.count("obs.test.bare") == 1
+    # only the span given the recorder is recorded
+    assert [e["name"] for e in rec.events] == ["obs.test.outer"]
+    assert rec.events[0]["args"] == {"step": 3}
+    assert TR.validate_chrome(rec.to_chrome()) == []
+
+
+def test_every_named_scope_is_listed():
+    """``SCOPES`` lists every scope the program opens inside jit."""
+    import re
+    src = os.path.join(os.path.dirname(__file__), "..", "src", "repro")
+    opened = set()
+    for d, _, files in os.walk(src):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    opened |= set(re.findall(
+                        r'(?:named_scope|named_phase|chunk_phase)\(\s*"'
+                        r'([^"]+)"', fh.read()))
+    assert {"train.grad", "train.optimizer", "comm.stage",
+            "comm.scatter"} <= opened
+    assert opened <= set(TR.SCOPES)
+
+
 def test_sim_trace_to_chrome_and_merge():
     from repro.sim import events as SE
     from repro.sim import scenarios as SC
@@ -341,7 +392,8 @@ def test_sim_trace_to_chrome_and_merge():
 def test_trainer_end_to_end_runlog_and_trace(tmp_path):
     """Trainer with telemetry + log_jsonl + trace_path: obs_* metrics in
     the history, a schema-valid run log the CI gate passes, and a valid
-    Chrome trace with train.step spans — the whole satellite pipeline."""
+    Chrome trace with the loop's train.batch / train.dispatch /
+    train.fetch spans — the whole satellite pipeline."""
     from repro.configs import get_config
     from repro.configs.base import InputShape
     from repro.models.model_factory import build_model
@@ -370,13 +422,16 @@ def test_trainer_end_to_end_runlog_and_trace(tmp_path):
     assert RL.alias_events(records) == 0
     steps = RL.step_records(records)
     assert steps and "obs_headroom" in steps[-1]["metrics"]
-    assert any(r.get("kind") == "span" and r["name"] == "train.step"
-               for r in records)
+    spans = [r["name"] for r in records if r.get("kind") == "span"]
+    assert spans.count("train.batch") == 4
+    assert spans.count("train.dispatch") == 4
+    assert spans.count("train.fetch") == 3          # log points 0, 2, 3
+    assert "train.step" not in spans
     assert any(r.get("kind") == "result" for r in records)
     with open(tr) as f:
         obj = json.load(f)
     assert TR.validate_chrome(obj) == []
-    assert any(e.get("name") == "train.step" and e.get("ph") == "X"
+    assert any(e.get("name") == "train.dispatch" and e.get("ph") == "X"
                for e in obj["traceEvents"])
 
 
