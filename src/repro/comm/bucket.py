@@ -30,10 +30,16 @@ and scatters the mixed result back.  Two invariants make the bucketed round
    rounding draws identical uniforms (Supp.-C shared randomness is
    preserved: the worker axis never enters the index).
 
-The single pad to the Pallas tile grid happens once, on the flat buffer,
-inside ``kernels/ops.py`` — and is sliced off again before the payload
-rolls, so tile padding never rides the wire and the bucketed Moniqua
-payload bytes equal the per-leaf sum exactly.
+The Moniqua round stages the same buffer in the codec kernels' tile shape
+instead: :meth:`BucketLayout.flatten_tiles` writes each segment straight
+into ``[n, R, 1024]`` (worker major, ``R = ceil(padded_elems / 1024)``),
+element ``e`` of leaf ``i`` at flat position ``offset_i + e`` as before,
+so the kernels read and write it in place with no per-worker slice, pad
+or stack.  Only the tail of the last row is padding, and it is the only
+padding the packed payload carries (at most one row); the ``[n, D]``
+rounds (``flatten``) slice their tile padding off inside
+``kernels/ops.py`` before the payload rolls, so their payload bytes equal
+the per-leaf sum exactly.
 
 Staging dtype: leaves sharing one floating dtype stage natively (a uniform
 bf16 tree ships bf16 on the full-precision wire); mixed-dtype trees stage
@@ -52,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.moniqua_encode import DEFAULT_BLOCK_COLS as TILE_COLS
 from repro.obs import trace as obs_trace
 
 PyTree = Any
@@ -200,14 +207,9 @@ class BucketLayout:
             buf = jnp.zeros((self.n_workers, self.padded_elems),
                             self.stage_dtype)
             for leaf, s in zip(leaves, self.slots):
-                seg = jnp.reshape(leaf, (self.n_workers, s.rows, s.last))
-                seg = seg.astype(self.stage_dtype)
-                if s.last_padded != s.last:
-                    seg = jnp.pad(seg, ((0, 0), (0, 0),
-                                        (0, s.last_padded - s.last)))
+                seg = _segment(leaf, s, self.n_workers)
                 buf = jax.lax.dynamic_update_slice(
-                    buf, seg.reshape(self.n_workers, s.padded_size),
-                    (0, s.offset))
+                    buf, seg.astype(self.stage_dtype), (0, s.offset))
         return buf
 
     def unflatten(self, flat: jax.Array) -> PyTree:
@@ -218,12 +220,93 @@ class BucketLayout:
             for s in self.slots:
                 seg = jax.lax.slice_in_dim(flat, s.offset,
                                            s.offset + s.padded_size, axis=1)
-                if s.last_padded != s.last:
-                    seg = seg.reshape(self.n_workers, s.rows, s.last_padded)
-                    seg = seg[..., :s.last]
-                out.append(seg.reshape((self.n_workers,) + s.shape)
-                           .astype(s.dtype))
+                out.append(_unsegment(seg, s, self.n_workers))
         return self.treedef.unflatten(out)
+
+    # -- the tile-staged buffer (the Moniqua round's kernels) ---------------
+    @property
+    def tile_rows(self) -> int:
+        """Rows ``R`` of the tile-staged buffer ``[n, R, TILE_COLS]``."""
+        return -(-self.padded_elems // TILE_COLS)
+
+    def flatten_tiles(self, X: PyTree) -> jax.Array:
+        """Stacked pytree -> ``[n, tile_rows, TILE_COLS]``: the buffer of
+        :meth:`flatten` with each worker's row laid out in rows of the
+        codec kernels' tile width (flat position ``p`` at ``[:, p //
+        TILE_COLS, p % TILE_COLS]``); the tail of the last row is zero.
+
+        No ``[n, padded_elems]`` array is formed.  Each segment, behind
+        the unfinished row the segments before it left, is written into
+        the buffer as whole rows; its remainder is the next unfinished
+        row.  The ``optimization_barrier`` keeps the shift of a segment to
+        its column out of the fusion that lays it out in rows: fused,
+        XLA's TPU compiler emits code for every (shape, shift) pair, which
+        made xlstm-125m's step program 82 MB larger, and the chip holds
+        the program in HBM."""
+        leaves = self.treedef.flatten_up_to(X)
+        n = leaves[0].shape[0]        # the workers held here (shard_map)
+        lead = _lead(n)
+        with obs_trace.named_phase("comm.stage"):
+            buf = jnp.zeros((n, self.tile_rows, TILE_COLS), self.stage_dtype)
+            row, head = 0, None           # head: the unfinished row
+            for leaf, s in zip(leaves, self.slots):
+                seg = _segment(leaf, s, n).astype(self.stage_dtype)
+                seg = seg.reshape(lead + seg.shape[1:])
+                if head is not None:
+                    seg = jnp.concatenate([head, seg], axis=-1)
+                whole = seg.shape[-1] // TILE_COLS * TILE_COLS
+                if whole:
+                    seg = jax.lax.optimization_barrier(seg)
+                    buf = jax.lax.dynamic_update_slice(
+                        buf, seg[..., :whole].reshape(n, -1, TILE_COLS),
+                        (0, row, 0))
+                    row += whole // TILE_COLS
+                head = seg[..., whole:] if whole < seg.shape[-1] else None
+            if head is not None:
+                buf = jax.lax.dynamic_update_slice(
+                    buf, head.reshape(n, 1, -1), (0, row, 0))
+        return buf
+
+    def unflatten_tiles(self, buf: jax.Array) -> PyTree:
+        """Inverse of :meth:`flatten_tiles`: read each segment back from
+        the rows it spans, drop row padding, restore each leaf's shape
+        and dtype."""
+        n = buf.shape[0]
+        lead = _lead(n)
+        out = []
+        with obs_trace.named_phase("comm.scatter"):
+            for s in self.slots:
+                row, col = divmod(s.offset, TILE_COLS)
+                end = -(-(s.offset + s.padded_size) // TILE_COLS)
+                win = jax.lax.slice_in_dim(buf, row, end, axis=1)
+                win = win.reshape(lead + (-1,))[..., col:col + s.padded_size]
+                out.append(_unsegment(win.reshape(n, -1), s, n))
+        return self.treedef.unflatten(out)
+
+
+def _lead(n: int) -> Tuple[int, ...]:
+    """The worker axis of the tile staging's flat segments.  One worker (a
+    device of a worker mesh) stages 1-D segments: XLA's TPU compiler lays
+    out a 1-D array in rows of 1024 but pads a ``[1, S]`` one to a tile of
+    sublanes, and its relayouts into ``[1, R, 1024]`` took three times the
+    code (v5e compile)."""
+    return (n,) if n > 1 else ()
+
+
+def _segment(leaf: jax.Array, s: LeafSlot, n: int) -> jax.Array:
+    """One stacked leaf as its ``[n, padded_size]`` segment (last axis
+    zero-padded to the alignment)."""
+    seg = jnp.reshape(leaf, (n, s.rows, s.last))
+    if s.last_padded != s.last:
+        seg = jnp.pad(seg, ((0, 0), (0, 0), (0, s.last_padded - s.last)))
+    return seg.reshape(n, s.padded_size)
+
+
+def _unsegment(seg: jax.Array, s: LeafSlot, n: int) -> jax.Array:
+    """Inverse of :func:`_segment`."""
+    if s.last_padded != s.last:
+        seg = seg.reshape(n, s.rows, s.last_padded)[..., :s.last]
+    return seg.reshape((n,) + s.shape).astype(s.dtype)
 
 
 @functools.lru_cache(maxsize=1024)

@@ -75,13 +75,19 @@ input to the analytic network model in ``benchmarks/``.  Payload bytes are
 path-independent (the vpb row alignment makes the bucketed payload equal
 the per-leaf sum exactly), so ``path="auto"`` never changes the ledger.
 
-Sharded meshes: the Moniqua backends tile each worker's slice separately
-(``kernels/ops.py`` stacked wrappers launch the kernels once per worker),
-so the only cross-worker traffic in a round is the packed
-collective-permute of the payload, and — because every worker hashes the
-same (seed, element) pairs — stochastic rounding uses Supp.-C shared
-randomness exactly: identical models encode to identical payloads on
-every worker.
+Tile staging (``CommEngine.staging``): the stateless Moniqua round on
+the bucketed path, with one tier, one chunk and no presence mask, stages
+the bucket in the codec kernels' tile shape ``[n, R, 1024]``
+(``BucketLayout.flatten_tiles``) and launches each kernel once over all
+workers; qsgd and the EF wires, chunked, tiered, masked and stale rounds
+and the telemetry keep the ``[n, D]`` buffer, where the ``kernels/ops.py``
+stacked wrappers tile each worker's slice separately.
+
+Sharded meshes: either way each worker is encoded on its own, so the
+only cross-worker traffic in a round is the packed collective-permute of
+the payload, and — because every worker hashes the same (seed, element)
+pairs — stochastic rounding uses Supp.-C shared randomness exactly:
+identical models encode to identical payloads on every worker.
 
 Elastic rounds (``presence=``): ``mix``/``mix_stale``/``pair_average``
 accept a per-worker presence mask.  A dead edge (either endpoint absent)
@@ -986,6 +992,23 @@ class CommEngine:
     def _use_bucketed(self, X: PyTree) -> bool:
         return self.resolved_path(X) == "bucketed"
 
+    def staging(self, X: PyTree, presence=None) -> str:
+        """The staging a :meth:`mix` round of ``X`` takes: ``"tiles"`` (the
+        bucket staged in the codec kernels' tile shape ``[n, R, 1024]``,
+        each kernel launched once over all workers), ``"flat"`` (the
+        ``[n, D]`` bucket, :class:`RoundPlan`/:class:`TieredPlan`) or
+        ``"per_leaf"``.  Tiles take the stateless Moniqua wire on the
+        bucketed path with one tier, one chunk and no presence mask;
+        segment statistics (qsgd, EF wires), chunk windows, tiers and
+        masks live in the ``[n, D]`` domain."""
+        if not self.tiered and not self._use_bucketed(X):
+            return "per_leaf"
+        if (self.codec.name == "moniqua" and not self.tiered
+                and self.chunks == 1
+                and _normalize_presence(presence, self.topo.n) is None):
+            return "tiles"
+        return "flat"
+
     def _shard_bucketed(self, shard: bucket.BucketChunk) -> bool:
         return self.resolved_path(None, shard=shard) == "bucketed"
 
@@ -1127,7 +1150,9 @@ class CommEngine:
         layout = self.layout(X)
         full_mixed_dtype = (self.codec.name == "full"
                             and not layout.uniform_dtype)
-        if self._use_bucketed(X) and not full_mixed_dtype:
+        if self.staging(X, presence) == "tiles":
+            Xm = self._mix_tiles(X, theta, key)
+        elif self._use_bucketed(X) and not full_mixed_dtype:
             Xm = layout.unflatten(
                 self.round_plan(X, theta=theta, key=key,
                                 presence=presence).run())
@@ -1158,6 +1183,34 @@ class CommEngine:
         health = (self._round_health(X, theta, key, None, presence)
                   if self.telemetry else None)
         return MixResult(Xm, {}, health)
+
+    def _mix_tiles(self, X: PyTree, theta, key: Optional[jax.Array]
+                   ) -> PyTree:
+        """The tile-staged Moniqua round (:meth:`staging` ``"tiles"``): the
+        kernels read and write the ``[n, R, 1024]`` staging buffer in
+        place, and the packed ``[n, R, 1024 / vpb]`` payload's roll is the
+        only cross-worker traffic.  Same per-element math, uniforms and
+        payload bytes as the ``[n, D]`` round (``tests/test_engine.py``)."""
+        self._require_key(key)
+        spec = self.codec.spec
+        backend = resolve_backend(self.backend)
+        layout = self.layout(X)
+        B = modulo.b_theta(theta, spec.delta)
+        # on a worker mesh each device stages its own workers' leaves
+        buf = kops.on_workers(layout.flatten_tiles, self.worker_axes, 1, X)
+        with obs_trace.named_phase("comm.encode"):
+            packed = kops.moniqua_encode_tiles(
+                buf, B, spec, kops._key_to_seed(key), backend=backend,
+                worker_axes=self.worker_axes)
+        with obs_trace.named_phase("comm.permute"):
+            nbrs = [gossip._roll(packed, o)
+                    for o in self.topo.neighbor_offsets()]
+        with obs_trace.named_phase("comm.decode_reduce"):
+            out = kops.moniqua_decode_reduce_tiles(
+                packed, nbrs, buf, B, self._neighbor_weights(), spec,
+                backend=backend, worker_axes=self.worker_axes)
+        return kops.on_workers(layout.unflatten_tiles, self.worker_axes, 1,
+                               out)
 
     def _mix_tiered(self, X: PyTree, theta, key: Optional[jax.Array],
                     ledger: Optional[BytesLedger],

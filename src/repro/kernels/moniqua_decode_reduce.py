@@ -1,9 +1,9 @@
 """Pallas TPU kernel: fused Moniqua decode-reduce (one gossip round's mixing).
 
 Receiver side of Algorithm 1 lines 4-6, fused across *all* neighbors.  Given
-the worker's own packed payload, the stack of its neighbors' packed payloads
-(already circulated by the quantized collective-permute), and the local model
-tile ``y``, produce in one VMEM pass
+the worker's own packed payload, its neighbors' packed payloads (already
+circulated by the quantized collective-permute, one operand each), and the
+local model tile ``y``, produce in one VMEM pass
 
     out = y + sum_s  w_s * (x_hat_s - x_hat_self)
 
@@ -136,62 +136,75 @@ def alias_band_mask(qb: jax.Array, y: jax.Array, B, theta) -> jax.Array:
     return jnp.abs(dhat) >= jnp.asarray(theta, jnp.float32)
 
 
-def _decode_reduce_kernel(ps_ref, pn_ref, y_ref, b_ref, *refs,
-                          bits: int, weights: tuple):
-    """``refs`` is ``(unpack_matrix_ref, o_ref)`` below 8 bits, else
-    ``(o_ref,)``.  Works one 128-byte chunk of the payloads at a time, which
+def _decode_reduce_kernel(ps_ref, *refs, bits: int, weights: tuple):
+    """``refs`` is the ``m = len(weights)`` neighbor payload refs, then
+    ``y_ref``, ``b_ref``, ``unpack_matrix_ref`` (below 8 bits only) and
+    ``o_ref``.  Works one 128-byte chunk of the payloads at a time, which
     also bounds the f32 temporaries to one chunk per neighbor."""
-    o_ref = refs[-1]
-    umat = refs[0][...] if len(refs) == 2 else None
+    m = len(weights)
+    pn_refs, (y_ref, b_ref), rest = refs[:m], refs[m:m + 2], refs[m + 2:]
+    o_ref = rest[-1]
+    umat = rest[0][...] if len(rest) == 2 else None
     B = b_ref[0]
     for cs, ps in chunk_loop(bits, y_ref.shape[1]):
         qb_self = tile_values(ps_ref[:, ps], bits, B, umat)
-        qb_nbrs = [tile_values(pn_ref[s, :, ps], bits, B, umat)
-                   for s in range(len(weights))]
+        qb_nbrs = [tile_values(r[:, ps], bits, B, umat) for r in pn_refs]
         out = decode_reduce_values(qb_self, qb_nbrs, y_ref[:, cs], B, weights)
         o_ref[:, cs] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "weights", "block_rows",
                                              "block_cols", "interpret"))
-def decode_reduce(p_self: jax.Array, p_nbrs: jax.Array, y2d: jax.Array,
+def decode_reduce(p_self: jax.Array, p_nbrs, y: jax.Array,
                   B: jax.Array, *, bits: int, weights: tuple,
                   block_rows: int = DEFAULT_BLOCK_ROWS,
                   block_cols: int = DEFAULT_BLOCK_COLS,
                   interpret: bool = False) -> jax.Array:
-    """Fused mix of ``m = len(weights)`` neighbor payloads into local ``y2d``.
+    """Fused mix of ``m = len(weights)`` neighbor payloads into local ``y``.
 
-    Shapes: ``p_self (rows, cols*bits/8)``, ``p_nbrs (m, rows, cols*bits/8)``
-    (neighbor s in topology offset order), ``y2d (rows, cols)``.
+    Shapes: ``y`` (rows, cols) or (workers, rows, cols), with
+    ``cols % block_cols == 0`` (a last row block may be ragged);
+    ``p_self`` and each of the ``m`` arrays of ``p_nbrs`` (neighbor s in
+    topology offset order, separate operands) are ``y``'s shape with
+    ``cols * bits / 8`` byte columns.
     """
-    rows, cols = y2d.shape
+    lead = y.shape[:-2]
+    y3 = y if y.ndim == 3 else y[None]
+    n, rows, cols = y3.shape
     vpb = 8 // bits
     m = len(weights)
-    if p_nbrs.shape != (m, rows, cols // vpb):
-        raise ValueError(f"p_nbrs {p_nbrs.shape} != {(m, rows, cols // vpb)}")
-    if cols % block_cols or rows % block_rows:
-        raise ValueError(f"shape {y2d.shape} not tiled by "
+    p_nbrs = tuple(p_nbrs)
+    want = lead + (rows, cols // vpb)
+    if len(p_nbrs) != m or any(p.shape != want
+                               for p in (p_self,) + p_nbrs):
+        raise ValueError(f"payloads {[p.shape for p in (p_self,) + p_nbrs]}"
+                         f" != 1 + {m} of {want}")
+    if cols % block_cols:
+        raise ValueError(f"shape {y.shape} not tiled by "
                          f"({block_rows},{block_cols}); pad in ops.py")
-    grid = (rows // block_rows, cols // block_cols)
+    grid = (n, pl.cdiv(rows, block_rows), cols // block_cols)
     kernel = functools.partial(_decode_reduce_kernel, bits=bits,
                                weights=tuple(weights))
-    in_specs = [
-        pl.BlockSpec((block_rows, block_cols // vpb), lambda i, j: (i, j)),
-        pl.BlockSpec((m, block_rows, block_cols // vpb),
-                     lambda i, j: (0, i, j)),
-        pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        pl.BlockSpec((1,), lambda i, j: (0,)),
+    p_spec = pl.BlockSpec((None, block_rows, block_cols // vpb),
+                          lambda w, i, j: (w, i, j))
+    in_specs = [p_spec] * (m + 1) + [
+        pl.BlockSpec((None, block_rows, block_cols),
+                     lambda w, i, j: (w, i, j)),
+        pl.BlockSpec((1,), lambda w, i, j: (0,)),
     ]
-    args = [p_self, p_nbrs, y2d, jnp.asarray(B, jnp.float32).reshape(1)]
+    args = [p.reshape(n, rows, cols // vpb) for p in (p_self,) + p_nbrs]
+    args += [y3, jnp.asarray(B, jnp.float32).reshape(1)]
     if vpb > 1:
         umat = packing.unpack_matrix(bits)
-        in_specs.append(pl.BlockSpec(umat.shape, lambda i, j: (0, 0)))
+        in_specs.append(pl.BlockSpec(umat.shape, lambda w, i, j: (0, 0)))
         args.append(umat)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), y2d.dtype),
+        out_specs=pl.BlockSpec((None, block_rows, block_cols),
+                               lambda w, i, j: (w, i, j)),
+        out_shape=jax.ShapeDtypeStruct((n, rows, cols), y.dtype),
         interpret=interpret,
     )(*args)
+    return out.reshape(y.shape)

@@ -42,6 +42,8 @@ def _encode_kernel(x_ref, seed_ref, b_ref, *refs, bits: int,
     """One (rows, cols) tile -> (rows, cols/vpb) packed tile.
 
     ``refs`` is ``(pack_matrix_ref, o_ref)`` below 8 bits, else ``(o_ref,)``.
+    The grid is ``(workers, row blocks, column blocks)``; the worker
+    (program id 0) never enters the element index.
 
     ``seed_ref`` carries two replicated uint32 scalars: the hash seed and
     ``idx_base``, the flat-index offset of this array inside a larger
@@ -53,8 +55,8 @@ def _encode_kernel(x_ref, seed_ref, b_ref, *refs, bits: int,
     levels = 2 ** bits
     vpb = 8 // bits
     rows, cols = x_ref.shape
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
 
     x = x_ref[...].astype(jnp.float32)
     B = b_ref[0]
@@ -64,7 +66,7 @@ def _encode_kernel(x_ref, seed_ref, b_ref, *refs, bits: int,
     lat = (r + 0.5) * levels - 0.5
 
     if stochastic:
-        # global flat element index (row-major over the full padded array)
+        # global flat element index (row-major over one worker's array)
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
         col_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
         g_rows = (row_ids + i * rows).astype(jnp.uint32)
@@ -90,44 +92,49 @@ def _encode_kernel(x_ref, seed_ref, b_ref, *refs, bits: int,
 
 @functools.partial(jax.jit, static_argnames=("bits", "stochastic", "block_rows",
                                              "block_cols", "interpret"))
-def encode(x2d: jax.Array, B: jax.Array, seed: jax.Array, *, bits: int,
+def encode(x: jax.Array, B: jax.Array, seed: jax.Array, *, bits: int,
            stochastic: bool = True,
            block_rows: int = DEFAULT_BLOCK_ROWS,
            block_cols: int = DEFAULT_BLOCK_COLS,
            interpret: bool = False,
            idx_base: jax.Array | int = 0) -> jax.Array:
-    """Encode a 2-D array (rows, cols) with cols % block_cols == 0.
+    """Encode ``x`` of shape (rows, cols) or (workers, rows, cols), with
+    ``cols % block_cols == 0``; a last row block may be ragged.
 
-    Returns packed uint8 of shape (rows, cols * bits / 8).  ``idx_base``
-    offsets the stochastic-rounding counter index (see ``_encode_kernel``).
+    Returns packed uint8 of shape (..., rows, cols * bits / 8).  Each
+    worker's (rows, cols) array hashes the same element indices, offset by
+    ``idx_base`` (see ``_encode_kernel``).
     """
-    rows, cols = x2d.shape
-    if cols % block_cols or rows % block_rows:
-        raise ValueError(f"shape {x2d.shape} not tiled by "
+    x3 = x if x.ndim == 3 else x[None]
+    n, rows, cols = x3.shape
+    if cols % block_cols:
+        raise ValueError(f"shape {x.shape} not tiled by "
                          f"({block_rows},{block_cols}); pad in ops.py")
     vpb = 8 // bits
-    grid = (rows // block_rows, cols // block_cols)
+    grid = (n, pl.cdiv(rows, block_rows), cols // block_cols)
     kernel = functools.partial(_encode_kernel, bits=bits,
                                stochastic=stochastic, ncols=cols)
     seed_base = jnp.stack([jnp.asarray(seed, jnp.uint32).reshape(()),
                            jnp.asarray(idx_base, jnp.uint32).reshape(())])
     in_specs = [
-        pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        pl.BlockSpec((2,), lambda i, j: (0,)),   # [seed, idx_base] (repl.)
-        pl.BlockSpec((1,), lambda i, j: (0,)),   # B    (replicated)
+        pl.BlockSpec((None, block_rows, block_cols),
+                     lambda w, i, j: (w, i, j)),
+        pl.BlockSpec((2,), lambda w, i, j: (0,)),   # [seed, idx_base]
+        pl.BlockSpec((1,), lambda w, i, j: (0,)),   # B    (replicated)
     ]
-    args = [x2d, seed_base, jnp.asarray(B, jnp.float32).reshape(1)]
+    args = [x3, seed_base, jnp.asarray(B, jnp.float32).reshape(1)]
     if vpb > 1:
         # constant block index: fetched into VMEM once for the whole grid
         pmat = packing.pack_matrix(bits)
-        in_specs.append(pl.BlockSpec(pmat.shape, lambda i, j: (0, 0)))
+        in_specs.append(pl.BlockSpec(pmat.shape, lambda w, i, j: (0, 0)))
         args.append(pmat)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, block_cols // vpb),
-                               lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols // vpb), jnp.uint8),
+        out_specs=pl.BlockSpec((None, block_rows, block_cols // vpb),
+                               lambda w, i, j: (w, i, j)),
+        out_shape=jax.ShapeDtypeStruct((n, rows, cols // vpb), jnp.uint8),
         interpret=interpret,
     )(*args)
+    return out if x.ndim == 3 else out[0]

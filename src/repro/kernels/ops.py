@@ -173,8 +173,8 @@ def moniqua_decode_reduce(p_self: jax.Array, p_nbrs: jax.Array, y: jax.Array,
     rows, pcols = y2d.shape[0], y2d.shape[1] // vpb
     p_need = rows * pcols
     ps2d = _p2d(p_self, p_need, rows, pcols)
-    pn2d = jnp.stack([_p2d(p_nbrs[s], p_need, rows, pcols)
-                      for s in range(p_nbrs.shape[0])])
+    pn2d = [_p2d(p_nbrs[s], p_need, rows, pcols)
+            for s in range(p_nbrs.shape[0])]
     out = _dr.decode_reduce(ps2d, pn2d, y2d, B, bits=spec.bits,
                             weights=tuple(float(w) for w in weights),
                             interpret=interpret)
@@ -285,6 +285,83 @@ def moniqua_decode_reduce_stacked(p_self: jax.Array, p_nbrs: jax.Array,
     return _per_worker(lambda ps, pn, yi, b: fn(ps, pn, yi, b, weights, spec),
                        worker_axes, (0, 1, 0, None), p_self, p_nbrs, y,
                        jnp.asarray(B, jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# Tile-staged launches: the whole round's buffer in the kernels' tile shape.
+#
+# ``BucketLayout.flatten_tiles`` stages the bucket as ``[n, R, 1024]``: each
+# worker's flat buffer laid out in rows of the kernels' tile width, worker
+# major.  Element ``e`` of worker ``w``'s buffer sits at ``[w, e // 1024,
+# e % 1024]``, so the tile index ``row * 1024 + col`` IS the flat index the
+# ``[n, D]`` round hashes (Supp. C: the worker never enters it).  Each
+# kernel then runs once over all workers (grid ``(n, row blocks, 1)``) and
+# reads and writes the staging buffer in place: no per-worker slice, no pad
+# to the tile grid, no stack of the outputs.  The payload keeps the tile
+# shape ``[n, R, 1024 / vpb]``; only its last row can carry padding.
+# ---------------------------------------------------------------------------
+
+def on_workers(fn, worker_axes: tuple, n_sharded: int, *args):
+    """``fn(*args)``, under ``shard_map`` over ``worker_axes`` when given:
+    the first ``n_sharded`` args (arrays or pytrees of them) carry the
+    worker axis at 0, the rest are replicated."""
+    if not worker_axes:
+        return fn(*args)
+    ax = P(tuple(worker_axes))
+    specs = (ax,) * n_sharded + (P(),) * (len(args) - n_sharded)
+    return jax.shard_map(fn, in_specs=specs, out_specs=ax,
+                         check_vma=False)(*args)
+
+
+def moniqua_encode_tiles(buf: jax.Array, B, spec: QuantSpec,
+                         seed: jax.Array, *, backend: str,
+                         worker_axes: tuple = ()) -> jax.Array:
+    """Encode the tile-staged buffer ``[n, R, cols]`` -> ``[n, R,
+    cols / vpb]`` packed bytes, every worker hashing the same flat indices
+    ``row * cols + col``."""
+    def pallas(x, b, s):
+        return _enc.encode(x, b, s, bits=spec.bits,
+                           stochastic=spec.stochastic,
+                           interpret=not _on_tpu())
+
+    def jnp_(x, b, s):
+        rows, cols = x.shape[1:]
+        idx = jnp.arange(rows * cols, dtype=jnp.uint32).reshape(rows, cols)
+        return pack_codes(kref.codes_ref(x, b, spec.bits, spec.stochastic,
+                                         s, idx), spec.bits)
+
+    return on_workers(pallas if backend == "pallas" else jnp_, worker_axes,
+                      1, buf, jnp.asarray(B, jnp.float32),
+                      jnp.asarray(seed, jnp.uint32))
+
+
+def moniqua_decode_reduce_tiles(p_self: jax.Array, p_nbrs, buf: jax.Array,
+                                B, weights, spec: QuantSpec, *,
+                                backend: str,
+                                worker_axes: tuple = ()) -> jax.Array:
+    """Fused decode-reduce of the tile-staged buffer ``[n, R, cols]``:
+    ``p_self`` and each neighbor payload of ``p_nbrs`` (one operand per
+    topology offset, in offset order) are ``[n, R, cols / vpb]``."""
+    weights = tuple(float(w) for w in weights)
+    m = len(weights)
+
+    def pallas(ps, *rest):
+        *pn, y, b = rest
+        return _dr.decode_reduce(ps, pn, y, b, bits=spec.bits,
+                                 weights=weights, interpret=not _on_tpu())
+
+    def jnp_(ps, *rest):
+        *pn, y, b = rest
+
+        def val(p):
+            return _dr.unpack_values(p, spec.bits, b)
+        out = _dr.decode_reduce_values(val(ps), [val(p) for p in pn], y, b,
+                                       weights)
+        return out.astype(y.dtype)
+
+    return on_workers(pallas if backend == "pallas" else jnp_, worker_axes,
+                      m + 2, p_self, *p_nbrs, buf,
+                      jnp.asarray(B, jnp.float32))
 
 
 # ---------------------------------------------------------------------------

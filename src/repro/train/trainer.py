@@ -11,6 +11,7 @@ Works at two scales with the same code path:
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -116,7 +117,16 @@ class Trainer:
         abstract = TS.abstract_state(model, self.algo, self.hp,
                                      tc.n_workers)
         self.hp.exact_engine().layout(abstract["params"])
-        self.hp.engine().layout(abstract["params"])
+        eng = self.hp.engine()
+        eng.layout(abstract["params"])
+        if tc.algo in ("moniqua", "moniqua_d2"):
+            # the buffer the quantized round stages in (CommEngine.staging);
+            # the one-round-stale round keeps the [n, D] buffer
+            staging = ("flat" if tc.algo == "moniqua"
+                       and tc.overlap == "stale" and not eng.stateful
+                       else eng.staging(abstract["params"], self.hp.presence))
+            print(f"trainer: gossip round staging {staging}",
+                  file=sys.stderr)
         self.step_fn = TS.make_train_step(model, self.hp, self.tcfg)
         self.mesh = mesh
         if mesh is None:

@@ -145,3 +145,54 @@ def test_shard_memoized_and_validated():
         layout.shard(2, 2)
     with pytest.raises(ValueError):
         layout.shard(2, -1)
+
+
+def _tile_tree(n=4):
+    """Segments off the 1024-element rows: whole rows between two partial
+    ones ("w"), one inside a row ("b"), a bf16 leaf and a scalar."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    return {
+        "w": jax.random.normal(ks[0], (n, 3, 1100)),
+        "b": jax.random.normal(ks[1], (n, 17)),
+        "c": jax.random.normal(ks[2], (n, 3, 7)).astype(jnp.bfloat16),
+        "s": jax.random.normal(ks[3], (n,)),
+    }
+
+
+@pytest.mark.parametrize("align", [1, 8])
+def test_tile_staging_is_the_flat_buffer_in_rows(align):
+    """``flatten_tiles`` is ``flatten``'s buffer laid out in rows of 1024
+    with a zero tail, and ``unflatten_tiles`` gives the leaves back."""
+    X = _tile_tree()
+    layout = bucket.layout_of(X, align)
+    tiles = layout.flatten_tiles(X)
+    D = layout.padded_elems
+    assert tiles.shape == (4, layout.tile_rows, bucket.TILE_COLS)
+    assert layout.tile_rows == -(-D // 1024) and D % 1024
+    rows = np.asarray(tiles).reshape(4, -1)
+    np.testing.assert_array_equal(rows[:, :D], np.asarray(layout.flatten(X)))
+    np.testing.assert_array_equal(rows[:, D:], 0.0)
+    out = layout.unflatten_tiles(tiles)
+    for k in X:
+        assert out[k].dtype == X[k].dtype and out[k].shape == X[k].shape
+        np.testing.assert_array_equal(
+            np.asarray(out[k], np.float32), np.asarray(X[k], np.float32))
+
+
+@pytest.mark.parametrize("sizes", [
+    (100,), (2048,), (3000,), (100, 50, 5000), (100, 924, 1024),
+    (1000, 24, 48, 3000), (2048, 1024, 1), (7, 7, 7, 2100)])
+def test_tile_staging_carries_unfinished_rows(sizes):
+    """Segments of any length starting anywhere in a row: each lands at
+    its flat position, behind the unfinished row the ones before it left,
+    and comes back unchanged."""
+    X = {f"l{i:02d}": jax.random.normal(jax.random.PRNGKey(i), (3, d))
+         for i, d in enumerate(sizes)}
+    layout = bucket.layout_of(X, 1)
+    rows = np.asarray(layout.flatten_tiles(X)).reshape(3, -1)
+    np.testing.assert_array_equal(rows[:, :layout.padded_elems],
+                                  np.asarray(layout.flatten(X)))
+    np.testing.assert_array_equal(rows[:, layout.padded_elems:], 0.0)
+    out = layout.unflatten_tiles(layout.flatten_tiles(X))
+    for k in X:
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(X[k]))

@@ -669,3 +669,136 @@ def test_init_wire_state_from_abstract_shapes():
     assert shaped["step"].dtype == jnp.int32
     stateless = CommEngine(ring(8), MoniquaWire())
     assert stateless.init_wire_state(X) == {}
+
+
+# ---------------------------------------------------------------------------
+# 5. the tile-staged round (CommEngine.staging() == "tiles")
+# ---------------------------------------------------------------------------
+
+# 1-bit stochastic rounding has delta 1/2, which Moniqua refuses
+TILE_SPECS = [(b, s) for b in BITS for s in (False, True) if b > 1 or not s]
+
+
+def _tile_tree():
+    """Segments that start and end off the 1024-element rows: one smaller
+    than a row, one spanning whole rows between two partial ones, a bf16
+    leaf and a scalar per worker."""
+    return {
+        "a": _stacked(d=2100, seed=1).reshape(8, 3, 700),
+        "b": _stacked(d=37, seed=2),
+        "c": _stacked(d=2900, seed=3),
+        "d": _stacked(d=275, seed=4).reshape(8, 5, 5, 11).astype(
+            jnp.bfloat16),
+        "s": _stacked(d=1, seed=5).reshape(8),
+    }
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("topo", [ring(8), exponential(8)],
+                         ids=lambda t: t.name)
+@pytest.mark.parametrize("bits,stochastic", TILE_SPECS,
+                         ids=[f"{b}bit-{'stoch' if s else 'nearest'}"
+                              for b, s in TILE_SPECS])
+def test_tile_round_matches_flat_round_bit_exact(bits, stochastic, topo,
+                                                 backend):
+    """The tile-staged round ships the ``[n, D]`` round's payload bytes
+    and mixes to its leaves, bit for bit; the ``[n, D]`` round is the
+    ``chunks=2`` one (bit-exact against ``chunks=1`` itself,
+    ``tests/test_overlap.py``)."""
+    from repro.kernels import ops as kops
+    spec = QuantSpec(bits=bits, stochastic=stochastic)
+    X = _tile_tree()
+    key = jax.random.PRNGKey(7)
+    tiles = CommEngine(topo, MoniquaWire(spec), backend=backend,
+                       path="bucketed")
+    flat = CommEngine(topo, MoniquaWire(spec), backend=backend,
+                      path="bucketed", chunks=2)
+    assert (tiles.staging(X), flat.staging(X)) == ("tiles", "flat")
+    layout = tiles.layout(X)
+    assert layout.padded_elems % 1024 and layout.tile_rows >= 3
+    # payload bytes
+    B = modulo.b_theta(0.5, spec.delta)
+    seed = kops._key_to_seed(key)
+    p_tiles = kops.moniqua_encode_tiles(layout.flatten_tiles(X), B, spec,
+                                        seed, backend=backend)
+    assert p_tiles.shape == (8, layout.tile_rows, 1024 // (8 // bits))
+    plan = flat.round_plan(X, theta=0.5, key=key)
+    p_flat = jnp.concatenate([plan.encode_chunk(i)[0]
+                              for i in range(plan.num_chunks)], axis=1)
+    np.testing.assert_array_equal(
+        np.asarray(p_tiles.reshape(8, -1)[:, :p_flat.shape[1]]),
+        np.asarray(p_flat))
+    # mixed leaves
+    got = tiles.mix(X, theta=0.5, key=key).x
+    want = flat.mix(X, theta=0.5, key=key).x
+    for k in X:
+        assert got[k].dtype == X[k].dtype and got[k].shape == X[k].shape
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]))
+    assert float(jnp.max(jnp.abs(got["c"] - X["c"]))) > 0
+
+
+def test_tile_round_forms_no_flat_buffer():
+    """The lowered tile-staged round holds no ``[n, D]`` array, so nothing
+    reshapes one into tiles; the ``[n, D]`` round holds one (the check
+    sees it when it is there)."""
+    import re
+    spec = QuantSpec(bits=1, stochastic=False)
+    X = _tile_tree()
+    key = jax.random.PRNGKey(7)
+
+    def lowered(chunks):
+        eng = CommEngine(ring(8), MoniquaWire(spec), backend="pallas",
+                         path="bucketed", chunks=chunks)
+        return eng, jax.jit(lambda x, k: eng.mix(x, theta=0.5, key=k).x
+                            ).lower(X, key).as_text()
+
+    eng, text = lowered(1)
+    layout = eng.layout(X)
+    flat_shapes = re.compile(
+        rf"tensor<8x({layout.padded_elems}|{layout.tile_rows * 1024})x")
+    assert not flat_shapes.search(text)
+    assert f"tensor<8x{layout.tile_rows}x1024xf32>" in text
+    assert flat_shapes.search(lowered(2)[1])
+
+
+def _cell_traffic():
+    import json
+    import os
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cells = json.load(f)["workloads"]
+    out = {}
+    for c in cells:
+        with open(os.path.join(root, "chipbench", "traffic",
+                               c["traffic"] + ".json")) as f:
+            out[c["name"]] = json.load(f)
+    return out
+
+
+def _cell_engine(t, **over):
+    from repro.train.trainer import TrainerConfig, build_hyper
+    fields = dict(algo="moniqua", topology=t["topology"],
+                  n_workers=t["n_workers"], bits=t["bits"],
+                  theta=t["theta"], wire=t["wire"], backend=t["backend"],
+                  comm_path=t["comm_path"], chunks=t["chunks"])
+    fields.update(over)
+    # as the Trainer does: the tiered round stages its own collectives
+    axes = (("data",) if t["placement"] == "worker_per_chip"
+            and fields.get("tiers", 1) <= 1 else ())
+    return build_hyper(TrainerConfig(**fields), axes).engine()
+
+
+@pytest.mark.parametrize("cell", sorted(_cell_traffic()))
+def test_every_benchmark_cell_stages_in_tiles(cell):
+    """Each benchmark cell's traffic settings take the tile-staged round;
+    ``chunks=4``, a two-tier topology and the ef_qsgd wire keep the
+    ``[n, D]`` buffer."""
+    t = _cell_traffic()[cell]
+    X = {"w": jnp.zeros((t["n_workers"], 3000)),
+         "b": jnp.zeros((t["n_workers"], 37))}
+    assert _cell_engine(t).staging(X) == "tiles"
+    assert _cell_engine(t).staging(X, presence=[1, 0, 1, 1]) == "flat"
+    assert _cell_engine(t, chunks=4).staging(X) == "flat"
+    assert _cell_engine(t, tiers=2).staging(X) == "flat"
+    assert _cell_engine(t, wire="ef_qsgd", bits=4).staging(X) == "flat"

@@ -37,6 +37,39 @@ def test_encode_bit_exact(bits, stochastic):
 
 
 @pytest.mark.parametrize("bits", BITS)
+def test_encode_over_workers_with_a_ragged_row_block(bits):
+    """The workers on the grid and a last row block that overhangs the
+    array: each worker's packed rows are the oracle's encode of its own
+    rows, every worker hashing the same element indices (Supp. C)."""
+    x = _tile(shape=(3, 300, 1024), seed=8)
+    p_k = ENC.encode(x, jnp.float32(4.0), jnp.uint32(5), bits=bits,
+                     stochastic=bits > 1, interpret=True)
+    assert p_k.shape == (3, 300, 1024 * bits // 8)
+    for w in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(p_k[w]),
+            np.asarray(R.encode_ref(x[w], 4.0, bits, bits > 1, 5)))
+
+
+@pytest.mark.parametrize("bits", [1, 8])
+def test_decode_reduce_over_workers_with_a_ragged_row_block(bits):
+    """The fused decode-reduce over ``[n, R, 1024]`` with one operand per
+    neighbour equals the shared per-element math, bit for bit."""
+    from repro.kernels import moniqua_decode_reduce as DR
+    y = _tile(shape=(3, 300, 1024), seed=9, scale=1.0)
+    B = jnp.float32(4.0)
+    ps = [ENC.encode(y + 0.1 * s, B, jnp.uint32(2), bits=bits,
+                     stochastic=False, interpret=True) for s in range(3)]
+    w = (0.25, 0.5)
+    got = DR.decode_reduce(ps[0], ps[1:], y, B, bits=bits, weights=w,
+                           interpret=True)
+    want = DR.decode_reduce_values(
+        DR.unpack_values(ps[0], bits, B),
+        [DR.unpack_values(p, bits, B) for p in ps[1:]], y, B, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("bits", BITS)
 @pytest.mark.parametrize("mode", ["remote", "self"])
 def test_decode_allclose(bits, mode):
     x = _tile(seed=1)

@@ -84,9 +84,39 @@ def test_decode_reduce_compiles_for_v5e(one_chip, bits, graph):
     w = tuple([1.0 / (m + 1)] * m)
     f = jax.jit(lambda ps, pn, y, B: DR.decode_reduce(
         ps, pn, y, B, bits=bits, weights=w))
-    c = f.lower(_sds((ROWS, COLS // vpb), jnp.uint8, one_chip),
-                _sds((m, ROWS, COLS // vpb), jnp.uint8, one_chip),
-                _sds((ROWS, COLS), jnp.float32, one_chip),
+    p = _sds((ROWS, COLS // vpb), jnp.uint8, one_chip)
+    c = f.lower(p, (p,) * m, _sds((ROWS, COLS), jnp.float32, one_chip),
+                _sds((), jnp.float32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+TILES = (4, 300, 1024)          # workers, rows (a ragged last block), cols
+
+
+@pytest.mark.parametrize("bits", [1, 2, 4, 8])
+def test_tile_encode_compiles_for_v5e(one_chip, bits):
+    """The tile-staged round's encode: the workers on the grid, a ragged
+    last row block, a bf16 staging buffer."""
+    f = jax.jit(lambda x, B, s: ENC.encode(x, B, s, bits=bits,
+                                           stochastic=bits > 1))
+    c = f.lower(_sds(TILES, jnp.bfloat16, one_chip),
+                _sds((), jnp.float32, one_chip),
+                _sds((), jnp.uint32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("graph", [ring(8), exponential(8)],
+                         ids=lambda t: t.name)
+@pytest.mark.parametrize("bits", [1, 8])
+def test_tile_decode_reduce_compiles_for_v5e(one_chip, bits, graph):
+    """The tile-staged round's decode-reduce: one payload operand per
+    neighbour, the workers on the grid, a ragged last row block."""
+    m = len(graph.neighbor_offsets())
+    w = tuple([1.0 / (m + 1)] * m)
+    f = jax.jit(lambda ps, pn, y, B: DR.decode_reduce(
+        ps, pn, y, B, bits=bits, weights=w))
+    p = _sds(TILES[:2] + (TILES[2] * bits // 8,), jnp.uint8, one_chip)
+    c = f.lower(p, (p,) * m, _sds(TILES, jnp.bfloat16, one_chip),
                 _sds((), jnp.float32, one_chip)).compile()
     assert _has_kernel(c)
 

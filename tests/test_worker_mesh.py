@@ -8,7 +8,9 @@ worker its own payload or dropped the exchange would not match the
 stacked round on one device.
 """
 import json
+import math
 import os
+import re
 import subprocess
 import sys
 
@@ -56,14 +58,21 @@ for topo in (ring(N), exponential(N)):
                 mix = jax.jit(lambda x, k: eng.mix(x, theta=THETA, key=k).x)
                 enc = jax.jit(lambda x, s: ops.moniqua_encode_stacked(
                     x, B, spec, s, backend=backend, worker_axes=axes))
+                layout = eng.layout(X)
+                enc_tiles = jax.jit(lambda x, s: ops.moniqua_encode_tiles(
+                    ops.on_workers(layout.flatten_tiles, axes, 1, x), B,
+                    spec, s, backend=backend, worker_axes=axes))
+                stage = eng.staging(X)
                 if not axes:
-                    return mix(X, key), enc(X, seed), None
+                    return (mix(X, key), enc(X, seed), enc_tiles(X, seed),
+                            None, stage)
                 with jax.set_mesh(mesh):
                     xs = jax.device_put(X, on_mesh)
                     text = mix.lower(xs, key).compile().as_text()
-                    return mix(xs, key), enc(xs, seed), text
-            ref, p_ref, _ = run(())
-            got, p_got, text = run(("data",))
+                    return (mix(xs, key), enc(xs, seed), enc_tiles(xs, seed),
+                            text, stage)
+            ref, p_ref, t_ref, _, _ = run(())
+            got, p_got, t_got, text, stage = run(("data",))
             ops_ = collective_ops(text)
             sum_w = sum(w for o, w in zip(topo.offsets, topo.weights)
                         if o % topo.n)
@@ -72,12 +81,15 @@ for topo in (ring(N), exponential(N)):
                 "moved": float(jnp.max(jnp.abs(ref - X))),
                 "level": B / 2 ** bits * sum_w,
                 "payload_bytes_differing": int(jnp.sum(p_got != p_ref)),
+                "tile_payload_bytes_differing": int(jnp.sum(t_got != t_ref)),
+                "staging": stage,
+                "payload_bytes": -(-D // spec.values_per_byte),
+                "row_bytes": 1024 // spec.values_per_byte,
                 "out_devices": len(got.sharding.device_set),
                 "neighbours": len(topo.neighbor_offsets()),
                 "permutes": [s for op, s in ops_
                              if op == "collective-permute"],
-                "f32_gathers": [s for op, s in ops_
-                                if op == "all-gather" and "f32[" in s],
+                "gathers": [s for op, s in ops_ if op == "all-gather"],
             }
 
 cfg = dataclasses.replace(get_config("llama3.2-3b").reduced(), num_layers=1,
@@ -133,8 +145,10 @@ def test_sharded_round_matches_stacked(mesh_results, name):
     """A ``mix`` round with one worker per device gives the stacked
     round's payload bytes and mixed values."""
     r = mesh_results["rounds"][name]
+    assert r["staging"] == "tiles"
     assert r["out_devices"] == 4
     assert r["payload_bytes_differing"] == 0
+    assert r["tile_payload_bytes_differing"] == 0
     # the round moves values by up to a quantization level; a swapped or
     # missing neighbour would move them by as much again
     assert r["moved"] > 0.1 * r["level"]
@@ -144,11 +158,16 @@ def test_sharded_round_matches_stacked(mesh_results, name):
 @pytest.mark.parametrize("name", ROUNDS)
 def test_sharded_round_permutes_packed_bytes(mesh_results, name):
     """The exchange crosses devices as uint8 collective-permutes, one per
-    neighbour offset, and never gathers the f32 buffer."""
+    neighbour offset, each no more than one 1024-element row of tile
+    padding above the packed bucket, and nothing is gathered."""
     r = mesh_results["rounds"][name]
     assert len(r["permutes"]) >= r["neighbours"], r["permutes"]
     assert all("u8[" in s for s in r["permutes"]), r["permutes"]
-    assert not r["f32_gathers"], r["f32_gathers"]
+    for s in r["permutes"]:
+        for dims in re.findall(r"u8\[([\d,]*)\]", s):
+            size = math.prod(int(d) for d in dims.split(",") if d)
+            assert size <= r["payload_bytes"] + r["row_bytes"], s
+    assert not r["gathers"], r["gathers"]
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
